@@ -37,6 +37,9 @@ import time
 
 
 def main() -> None:
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma list: fig2,table1,table2,table3")

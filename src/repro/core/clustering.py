@@ -11,14 +11,15 @@ embedding source is pluggable:
     (``repro.models.embedder``) stands in for the math-BERT.
 
 ``cluster_embeddings`` mirrors the paper: scipy hierarchical agglomerative
-clustering on cosine distance with a fixed threshold.  A pure-numpy
-fallback implements single-linkage agglomeration for environments without
-scipy.
+clustering on cosine distance with a fixed threshold.  SciPy is a hard
+dependency; ``_single_linkage`` is a pure-numpy single-linkage reference
+for the tests.
 """
 from __future__ import annotations
 
-
 import numpy as np
+from scipy.cluster.hierarchy import fcluster, linkage
+from scipy.spatial.distance import squareform
 
 
 def cosine_distance_matrix(embs: np.ndarray) -> np.ndarray:
@@ -40,19 +41,15 @@ def cluster_embeddings(embs: np.ndarray, threshold: float = 0.3,
     L = embs.shape[0]
     if L <= 1:
         return np.zeros((L,), dtype=np.int64)
-    try:
-        from scipy.cluster.hierarchy import fcluster, linkage
-        from scipy.spatial.distance import squareform
-        dm = cosine_distance_matrix(embs)
-        condensed = squareform(dm, checks=False)
-        Z = linkage(condensed, method=method)
-        return fcluster(Z, t=threshold, criterion="distance").astype(np.int64)
-    except ImportError:
-        return _single_linkage(cosine_distance_matrix(embs), threshold)
+    dm = cosine_distance_matrix(embs)
+    condensed = squareform(dm, checks=False)
+    Z = linkage(condensed, method=method)
+    return fcluster(Z, t=threshold, criterion="distance").astype(np.int64)
 
 
 def _single_linkage(dm: np.ndarray, threshold: float) -> np.ndarray:
-    """Union-find single-linkage fallback."""
+    """Union-find single linkage: the plain reference that
+    ``cluster_embeddings(method="single")`` is tested against."""
     L = dm.shape[0]
     parent = list(range(L))
 

@@ -14,8 +14,8 @@ s.t.       n_v >= s_i          for every leaf i whose path contains v
 
 The paper solves this with PuLP + CBC; we use scipy.optimize.milp (HiGHS),
 which is the maintained off-the-shelf MILP stack in the scientific-python
-world.  ``greedy_select`` is a host-side fallback with the same objective
-(used when HiGHS is unavailable and as the low-latency beyond-paper path).
+world.  ``greedy_select`` is a host-side heuristic with the same objective
+(``solve(..., method="greedy")``: the low-latency beyond-paper path).
 """
 from __future__ import annotations
 
@@ -148,7 +148,7 @@ def milp_select(prob: SelectionProblem) -> SelectionResult:
 
 
 # ---------------------------------------------------------------------------
-# Greedy fallback (also the low-host-latency beyond-paper selector)
+# Greedy selector (the low-host-latency beyond-paper path)
 # ---------------------------------------------------------------------------
 
 def greedy_select(prob: SelectionProblem) -> SelectionResult:
@@ -190,10 +190,7 @@ def greedy_select(prob: SelectionProblem) -> SelectionResult:
 
 def solve(prob: SelectionProblem, method: str = "milp") -> SelectionResult:
     if method == "milp":
-        try:
-            return milp_select(prob)
-        except ImportError:
-            return greedy_select(prob)
+        return milp_select(prob)
     if method == "greedy":
         return greedy_select(prob)
     raise ValueError(method)

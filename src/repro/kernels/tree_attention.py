@@ -27,11 +27,24 @@ The TPU grid is sequential in the trailing axis, so for each leaf tile
 the page axis sweeps with flash-style running (m, l, acc) scratch that
 is (re)initialized at ``n == 0`` and normalized at ``n == N - 1``.  A
 page tile is attended against one *leaf tile* at a time, so the fp32
-scratch is per-tile — ``(block_b, K, G[, hd])`` — instead of spanning
-the whole batch, and ``max_batch`` can grow without growing VMEM
-residency (pages are re-streamed once per leaf tile; tile counts are
-small, and the default tile keeps the single-tile IO profile for every
-batch the serving engine currently runs).
+scratch is per-tile instead of spanning the whole batch, and
+``max_batch`` can grow without growing VMEM residency (pages are
+re-streamed once per leaf tile; tile counts are small, and the default
+tile keeps the single-tile IO profile for every batch the serving
+engine currently runs).
+
+Layout (what lets Mosaic compile the body: every block is 2-D, and no
+reshape or transpose happens inside the kernel).  The wrapper lays the
+tile's R = block_b * H query rows on lanes (q enters as (hd, B*H)), and
+a page enters as its (S*K, hd) slab, slot-major with the kv head minor.
+One matmul scores every query row against every slot of every kv head;
+the mask keeps the columns of the row's own kv head (GQA) among the
+page's valid slots, for rows whose leaf descends from the page.  The
+per-page leaf mask arrives as ``lane_kv`` (N, B*H) int32 — the kv head
+of each query row, or -1 — in (8, R) blocks, eight pages per block, so
+the block obeys the (8, 128) tiling rule.  Multi-tile grids therefore
+need R to be a multiple of 128 on the chip; a single tile may have any
+size.
 
 Padding contract (shared with ``build_tree_metadata`` below): the page
 axis N is padded to a power of two with *dump entries* — any in-range
@@ -42,11 +55,10 @@ a fully-masked row produces an all-zero output (no NaNs).  The wrapper
 itself pads B up to a multiple of the leaf tile with such inactive rows
 and slices them off the output, so callers never see the tile size.
 
-VMEM budget (per-tile): scratch is block_b*K*G*(hd+2) fp32 — e.g.
-block_b=64, H=32 (K*G=32), hd=128 -> 1.06 MiB + one (S, K, hd) page
-tile, independent of B.  The old single-level grid held (B, K, G, hd)
-for the whole batch (B=256 at the same config -> 4 MiB), which is what
-capped ``max_batch``; now batch growth adds leaf tiles, not scratch.
+VMEM budget (per-tile): scratch is (hd + 16) * R fp32 (m and l are
+(1, R) rows padded to eight sublanes) — e.g. block_b=64, H=32, hd=64
+-> 640 KiB — plus the double-buffered (hd, R) q and output blocks and
+two (S*K, hd) page slabs, independent of B.
 """
 from __future__ import annotations
 
@@ -144,13 +156,19 @@ def build_tree_metadata(block_tables: Sequence[Sequence[int]],
 
 
 def _kernel(page_list_ref, page_lens_ref,       # scalar prefetch
-            q_ref, k_ref, v_ref, mask_ref,      # VMEM
-            o_ref,
+            qt_ref, k_ref, v_ref, lane_kv_ref,  # VMEM
+            ot_ref,
             m_ref, l_ref, acc_ref,
-            *, scale: float):
+            *, scale: float, n_kv_heads: int):
     # grid (B // block_b, N): the page axis trails, so the flash
     # (m, l, acc) carry below sweeps all pages for one leaf tile before
     # the tile advances (scratch re-inits at n == 0 per tile).
+    #
+    # Layout: lanes are the tile's query rows r = leaf * H + head, so
+    # every operand is 2-D and no head regrouping happens in the body.
+    # One page is the (S*K, hd) slab of its slots x kv heads; a row only
+    # attends to the columns of its own kv head (GQA), which the mask
+    # below enforces — columns c = slot * K + kv_head.
     n = pl.program_id(1)
     N = pl.num_programs(1)
 
@@ -160,49 +178,39 @@ def _kernel(page_list_ref, page_lens_ref,       # scalar prefetch
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[...].astype(jnp.float32)                    # (B, H, hd)
-    k = k_ref[0].astype(jnp.float32)                      # (S, K, hd)
-    v = v_ref[0].astype(jnp.float32)
-    leaf_mask = mask_ref[0] > 0                           # (B,)
-    n_valid = page_lens_ref[n]
-
-    B, H, hd = q.shape
-    S, K, _ = k.shape
-    G = H // K
-    qg = q.reshape(B, K, G, hd)
-    # per-kv-head batched dot: (K, B*G, hd) x (K, S, hd) -> (K, B*G, S)
-    qk = qg.transpose(1, 0, 2, 3).reshape(K, B * G, hd)   # (K, B*G, hd)
-    kk = k.transpose(1, 0, 2)                             # (K, S, hd)
+    qt = qt_ref[...].astype(jnp.float32)                  # (hd, R)
+    k = k_ref[...].astype(jnp.float32)                    # (C, hd)
+    v = v_ref[...].astype(jnp.float32)
+    # this page's row of the (8, R) lane-kv block: kv head of each query
+    # row whose leaf descends from the page, -1 elsewhere
+    sub = jax.lax.broadcasted_iota(jnp.int32, lane_kv_ref.shape, 0)
+    lane_kv = jnp.max(jnp.where(sub == n % 8, lane_kv_ref[...], -1),
+                      axis=0, keepdims=True)              # (1, R)
+    C = k.shape[0]
+    R = qt.shape[1]
     s = jax.lax.dot_general(
-        qk, kk, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)               # (K, B*G, S)
-    s = (s * scale).reshape(K, B, G, S).transpose(1, 0, 2, 3)  # (B,K,G,S)
-
-    slot_ok = jax.lax.broadcasted_iota(jnp.int32, (B, K, G, S), 3) < n_valid
-    ok = slot_ok & leaf_mask[:, None, None, None]
+        k, qt, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale       # (C, R)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, R), 0)
+    ok = (col < page_lens_ref[n] * n_kv_heads) \
+        & (col % n_kv_heads == lane_kv)
     s = jnp.where(ok, s, NEG_INF)
 
-    m_prev = m_ref[...]                                   # (B, K, G)
-    l_prev = l_ref[...]
-    m_cur = jnp.max(s, axis=-1)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
+    m_prev = m_ref[...]                                   # (1, R)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
     alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1)
-    pk = p.transpose(1, 0, 2, 3).reshape(K, B * G, S)
-    vv = v.transpose(1, 0, 2)                             # (K, S, hd)
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0, keepdims=True)
     pv = jax.lax.dot_general(
-        pk, vv, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)               # (K, B*G, hd)
-    pv = pv.reshape(K, B, G, hd).transpose(1, 0, 2, 3)
-    acc_ref[...] = acc_ref[...] * alpha[..., None] + pv
+        v, p, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)               # (hd, R)
+    acc_ref[...] = acc_ref[...] * alpha + pv
     m_ref[...] = m_new
 
     @pl.when(n == N - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        out = acc_ref[...] / l[..., None]                 # (B, K, G, hd)
-        o_ref[...] = out.reshape(B, K * G, hd).astype(o_ref.dtype)
+        ot_ref[...] = (acc_ref[...] / l).astype(ot_ref.dtype)
 
 
 # Default leaf tile: one tile up to this batch size (the IO profile of
@@ -231,33 +239,46 @@ def tree_attention(q, k_pool, v_pool, page_list, page_mask, page_lens, *,
     if Bp != B:
         q = jnp.pad(q, ((0, Bp - B), (0, 0), (0, 0)))
         page_mask = jnp.pad(page_mask, ((0, 0), (0, Bp - B)))
+    R = block_b * H
+    # lane-major operands: query rows (leaf * H + head) on lanes
+    qt = q.reshape(Bp * H, hd).T                          # (hd, Bp*H)
+    head_kv = jnp.arange(H, dtype=jnp.int32) // G
+    lane_kv = jnp.where(page_mask[:, :, None] > 0, head_kv[None, None, :],
+                        -1).reshape(N, Bp * H).astype(jnp.int32)
+    if N % 8:
+        lane_kv = jnp.pad(lane_kv, ((0, 8 - N % 8), (0, 0)),
+                          constant_values=-1)
+    # a page is its (S*K, hd) slab: slot-major, kv head minor
+    kp = k_pool.reshape(P, S * K, hd)
+    vp = v_pool.reshape(P, S * K, hd)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(Bp // block_b, N),
         in_specs=[
-            pl.BlockSpec((block_b, H, hd),
-                         lambda b, n, pls, pln: (b, 0, 0)),
-            pl.BlockSpec((1, S, K, hd),
-                         lambda b, n, pls, pln: (pls[n], 0, 0, 0)),
-            pl.BlockSpec((1, S, K, hd),
-                         lambda b, n, pls, pln: (pls[n], 0, 0, 0)),
-            pl.BlockSpec((1, block_b), lambda b, n, pls, pln: (n, b)),
+            pl.BlockSpec((hd, R), lambda b, n, pls, pln: (0, b)),
+            pl.BlockSpec((None, S * K, hd),
+                         lambda b, n, pls, pln: (pls[n], 0, 0)),
+            pl.BlockSpec((None, S * K, hd),
+                         lambda b, n, pls, pln: (pls[n], 0, 0)),
+            # eight pages' rows per block (the (8, 128) tiling); the
+            # kernel picks row n % 8
+            pl.BlockSpec((8, R), lambda b, n, pls, pln: (n // 8, b)),
         ],
-        out_specs=pl.BlockSpec((block_b, H, hd),
-                               lambda b, n, pls, pln: (b, 0, 0)),
+        out_specs=pl.BlockSpec((hd, R), lambda b, n, pls, pln: (0, b)),
         scratch_shapes=[
-            pltpu.VMEM((block_b, K, G), jnp.float32),
-            pltpu.VMEM((block_b, K, G), jnp.float32),
-            pltpu.VMEM((block_b, K, G, hd), jnp.float32),
+            pltpu.VMEM((1, R), jnp.float32),
+            pltpu.VMEM((1, R), jnp.float32),
+            pltpu.VMEM((hd, R), jnp.float32),
         ],
     )
-    kernel = functools.partial(_kernel, scale=scale)
+    kernel = functools.partial(_kernel, scale=scale, n_kv_heads=K)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Bp, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((hd, Bp * H), q.dtype),
         interpret=interpret,
     )(page_list.astype(jnp.int32), page_lens.astype(jnp.int32),
-      q, k_pool, v_pool, page_mask.astype(jnp.int8))
+      qt, kp, vp, lane_kv)
+    out = out.T.reshape(Bp, H, hd)
     return out[:B] if Bp != B else out

@@ -243,10 +243,15 @@ class PagedEngine:
         khd = max(cfg.head_dim, 1)
         kv_sharding = None
         if self.mesh is not None:
-            from jax.sharding import NamedSharding
+            from jax.sharding import NamedSharding, PartitionSpec
             from repro.kernels.ops import check_mesh_compat
             from repro.launch.sharding import pool_spec
             check_mesh_compat(self.mesh, use_kernel=ecfg.use_kernel)
+            # commit the weights to this engine's devices once, so each
+            # step runs where its pool lives (a replica on its own chip)
+            # and never re-transfers them
+            self.params = jax.device_put(
+                params, NamedSharding(self.mesh, PartitionSpec()))
             pool_shape = (L, ecfg.n_pages, ecfg.page_size, kvh, khd)
             kv_sharding = NamedSharding(
                 self.mesh, pool_spec(self.mesh, pool_shape,

@@ -39,6 +39,10 @@ def prm_loss_fn(model, params, batch) -> jnp.ndarray:
 
 def _fit(model, params, make_batch, loss_fn, tcfg: TrainConfig,
          log_prefix: str) -> Tuple[dict, list]:
+    if tcfg.steps <= 0:
+        # no optimizer state: at published widths AdamW's two moments
+        # would double the weights' device memory for nothing
+        return params, []
     opt_state = adamw_init(params)
     opt_cfg = dataclasses.replace(tcfg.opt, total_steps=tcfg.steps)
 

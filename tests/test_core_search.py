@@ -172,6 +172,20 @@ def test_clustering_single_point():
     assert cluster_embeddings(np.ones((1, 4))).shape == (1,)
 
 
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_single_linkage_matches_reference(seed):
+    """SciPy single linkage cuts the same partition as the union-find
+    reference (labels may be numbered differently)."""
+    from repro.core.clustering import _single_linkage, cosine_distance_matrix
+    embs = np.random.default_rng(seed).normal(size=(12, 6))
+    got = cluster_embeddings(embs, threshold=0.6, method="single")
+    want = _single_linkage(cosine_distance_matrix(embs), 0.6)
+    pairs = lambda lab: {(i, j) for i in range(len(lab))
+                         for j in range(len(lab)) if lab[i] == lab[j]}
+    assert pairs(got) == pairs(want)
+    assert 1 < len(set(want)) < len(want)    # some merges, not all
+
+
 # ---------------------------------------------------------------------------
 # ets_prune integration
 # ---------------------------------------------------------------------------

@@ -44,3 +44,43 @@ def test_report_cli_runs():
     r = _run(["repro.analysis.report"], timeout=120)
     assert r.returncode == 0
     assert "Roofline" in r.stdout
+
+
+_CACHE_PROBE = """
+import json
+import jax
+from repro.launch.cache import use_compile_cache
+print(json.dumps([use_compile_cache(), jax.config.jax_compilation_cache_dir]))
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(tmp_path, from_env):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and nothing else is set in
+    code; without it the cache sits at the checkout's fixed
+    ``.jax_cache/``."""
+    env = {k: v for k, v in ENV.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    want = os.path.join(REPO, ".jax_cache")
+    if from_env:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    r = subprocess.run([sys.executable, "-c", _CACHE_PROBE], cwd=tmp_path,
+                       env=dict(env, JAX_PLATFORMS="cpu"),
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert json.loads(r.stdout.strip().splitlines()[-1]) == [want, want]
+
+
+def test_build_stack_seeded_weights_keep_published_vocab():
+    """``train_steps=0`` serves seeded weights at the config's own vocab
+    (PRM and embedder share it), on the jnp paths off the TPU."""
+    from repro.configs import get_config
+    from repro.launch.serve import build_stack
+
+    (backend,), scfg = build_stack("tiny-lm", width=4, train_steps=0)
+    vocab = get_config("tiny-lm").vocab_size
+    assert backend.engine.cfg.vocab_size == vocab
+    assert backend.prm_model.cfg.vocab_size == vocab
+    assert backend.embed_model.cfg.vocab_size == vocab
+    assert backend.engine.ecfg.attention == "tree"
+    assert backend.engine.ecfg.use_kernel is False
+    assert scfg.method == "ets" and scfg.width == 4

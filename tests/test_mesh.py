@@ -10,6 +10,9 @@ Equivalence contracts of the distributed serving layer:
     RNG namespaces are seeded from the backend seed alone, so which
     replica runs a problem is invisible to its streams) —
     property-tested over random routers and arrival patterns;
+  * on four devices, a ``model=4`` mesh engine decodes what the
+    mesh-less engine decodes, and ``replica_meshes`` gives each replica
+    its own devices;
   * ``make_host_mesh`` rejects non-divisible model-axis sizes up front;
   * the Pallas wrapper seam refuses multi-device meshes (the kernels
     are per-device until wrapped in shard_map).
@@ -244,3 +247,112 @@ def test_serving_loop_submit_matches_constructor():
     for i, r in enumerate(reqs):
         loop.submit(i, r)
     _assert_results_identical(want, loop.run())
+
+
+# ---------------------------------------------------------------------------
+# Multi-device placement (four virtual CPU devices, in a child process)
+# ---------------------------------------------------------------------------
+
+# A model=4 mesh needs four devices, and the CPU backend takes its
+# device count from XLA_FLAGS before JAX starts: run it in a child.
+_MODEL4_PROBE = """
+import dataclasses, json, sys
+import jax, numpy as np
+from repro.configs import get_config
+from repro.launch.mesh import make_host_mesh
+from repro.models.model import build_model
+from repro.serving.engine import EngineConfig, PagedEngine
+
+cfg = dataclasses.replace(get_config("tiny-lm"), n_layers=2, d_model=64,
+                          n_heads=4, n_kv_heads=2, d_ff=128)
+lm = build_model(cfg, remat=False)
+params = lm.init(jax.random.key(0))
+
+def probe(mesh):
+    eng = PagedEngine(lm, params, EngineConfig(
+        n_pages=64, page_size=8, max_batch=8, max_seq_len=64,
+        attention=sys.argv[1], trace_logits=True, mesh=mesh))
+    roots = eng.prefill_many([list(range(4, 4 + n)) for n in (17, 9)])
+    kids = [k for r in roots for k in eng.branch(r, 2)]
+    out = eng.decode(kids, 5, temperature=0.0,
+                     row_keys=jax.random.split(jax.random.key(1), len(kids)))
+    eng.alloc.check_invariants()
+    devs = len(eng.pool.k.devices())
+    return [out[k] for k in kids], [np.asarray(a) for a in eng.logits_trace], devs
+
+want, want_logits, _ = probe(None)
+got, got_logits, devs = probe(make_host_mesh(model=4))
+gap = max(float(abs(a - b).max()) for a, b in zip(got_logits, want_logits))
+print(json.dumps({"devices": jax.device_count(), "pool_devices": devs,
+                  "same_tokens": got == want, "gap": gap}))
+"""
+
+
+def _run_on_four_devices(probe, *args):
+    """Run ``probe`` in a child with four CPU devices; returns the JSON
+    object it printed last."""
+    import json
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"),
+               JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    r = subprocess.run([sys.executable, "-c", probe, *args],
+                       cwd=repo, env=env, capture_output=True, text=True,
+                       timeout=600)
+    assert r.returncode == 0, r.stderr[-2000:]
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("attention", ["tree", "paged"])
+def test_model4_mesh_engine_matches_meshless(attention):
+    """A model=4 mesh engine (pool pages sharded over four devices, jnp
+    attention) decodes the same greedy tokens as the mesh-less engine,
+    with logits equal to f32 tolerance."""
+    res = _run_on_four_devices(_MODEL4_PROBE, attention)
+    assert res["devices"] == 4 and res["pool_devices"] == 4, res
+    assert res["same_tokens"], res
+    assert res["gap"] <= 1e-5, res
+
+
+_REPLICA_PROBE = """
+import dataclasses, json
+import jax
+from repro.configs import get_config
+from repro.launch.mesh import replica_meshes
+from repro.models.model import build_model
+from repro.serving.engine import EngineConfig, PagedEngine
+
+ids = lambda mesh: [d.id for d in mesh.devices.flat]
+cfg = dataclasses.replace(get_config("tiny-lm"), n_layers=1, d_model=64,
+                          n_heads=4, n_kv_heads=2, d_ff=128)
+lm = build_model(cfg, remat=False)
+mesh = replica_meshes(4)[2]
+eng = PagedEngine(lm, lm.init(jax.random.key(0)), EngineConfig(
+    n_pages=16, page_size=8, max_batch=8, max_seq_len=64, mesh=mesh))
+print(json.dumps({
+    "four": [ids(m) for m in replica_meshes(4)],
+    "two_by_model2": [[ids(m), m.shape["model"]]
+                      for m in replica_meshes(2, model=2)],
+    "three": [ids(m) for m in replica_meshes(3)],
+    "six": [ids(m) for m in replica_meshes(6)],
+    "pool": sorted(d.id for d in eng.pool.k.devices()),
+    "params": sorted({d.id for leaf in jax.tree.leaves(eng.params)
+                      for d in leaf.devices()}),
+}))
+"""
+
+
+def test_replica_meshes_place_replicas_on_disjoint_devices():
+    """Replicas split the host's devices into disjoint equal groups (a
+    remainder idles; with fewer devices than replicas they share
+    round-robin), and an engine commits its pool and weights to its
+    own mesh."""
+    res = _run_on_four_devices(_REPLICA_PROBE)
+    assert res["four"] == [[0], [1], [2], [3]]
+    assert res["two_by_model2"] == [[[0, 1], 2], [[2, 3], 2]]
+    assert res["three"] == [[0], [1], [2]]
+    assert res["six"] == [[0], [1], [2], [3], [0], [1]]
+    assert res["pool"] == [2] and res["params"] == [2]

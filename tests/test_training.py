@@ -123,3 +123,19 @@ def test_prm_short_fit():
     _, hist = train_prm(model, params, task,
                         TrainConfig(steps=60, batch=16, log_every=30))
     assert hist[-1] < hist[0]
+
+
+@pytest.mark.parametrize("fit", [train_lm, train_prm])
+def test_zero_steps_allocates_no_optimizer_state(fit, monkeypatch):
+    """``steps=0`` serves the given weights untouched and never builds
+    AdamW state (which would double device memory at published widths)."""
+    from repro.training import train as train_mod
+
+    def no_opt_state(params):
+        raise AssertionError("optimizer state allocated for 0 steps")
+
+    monkeypatch.setattr(train_mod, "adamw_init", no_opt_state)
+    params = {"w": jnp.ones((3,))}
+    out, hist = fit(None, params, ArithmeticTask(n_ops=2, seq_len=48),
+                    TrainConfig(steps=0))
+    assert out is params and hist == []
