@@ -107,6 +107,8 @@ from typing import (Any, Dict, List, Optional, Protocol, Sequence, Tuple,
 
 import numpy as np
 
+from repro.obs import span, spanned
+
 from .ets import ETSConfig, ets_prune, mcts_step
 from .rebase import rebase_weights
 from .tree import SearchTree
@@ -310,8 +312,7 @@ def _tree_ns(tree: SearchTree):
     return pl.get("ns") if isinstance(pl, dict) else None
 
 
-def _release_problem(backend, tree: SearchTree,
-                     stats: Optional["SweepStats"] = None) -> None:
+def _release_problem(backend, tree: SearchTree) -> None:
     """Retire one problem's backend state through ``finish_problem``.
 
     The single place the hook is looked up (``run_search``'s retirement,
@@ -319,9 +320,9 @@ def _release_problem(backend, tree: SearchTree,
     route here).  A backend that holds pool pages (``capacity()`` not
     None) but exposes no — or a misspelled — ``finish_problem`` silently
     leaks its namespace pages until the pool runs dry, so the miss is
-    counted on the sweep stats (``finish_hook_missing``) and warned
-    about; backends without page accounting (synthetic oracles, engine
-    doubles) legitimately have nothing to release and stay silent.
+    warned about; backends without page accounting (synthetic oracles,
+    engine doubles) legitimately have nothing to release and stay
+    silent.
     After the hook runs, the problem's per-ns page accounting must read
     zero — asserted whenever the backend can report it.
     """
@@ -329,8 +330,6 @@ def _release_problem(backend, tree: SearchTree,
     cap_fn = getattr(backend, "capacity", None)
     holds_pages = cap_fn is not None and cap_fn() is not None
     if fin is None:
-        if stats is not None:
-            stats.finish_hook_missing += 1
         if holds_pages:
             warnings.warn(
                 "backend holds pool pages but defines no finish_problem "
@@ -526,6 +525,7 @@ class SearchState:
         self.phase = "embeds"
         return list(open_c) if need_embs else []
 
+    @spanned("search.select")
     def complete_step(self, embs: Optional[np.ndarray] = None) -> None:
         """Apply the retention policy and close the step."""
         assert self.phase == "embeds", self.phase
@@ -685,17 +685,11 @@ class SweepStats:
     demotions: int = 0
     resumes: int = 0
     max_reserved_pages: int = 0
-    # per global step: live problems and total branch demand they posted.
-    # ``problems_per_step`` has one entry per global step;
-    # ``demand_per_step`` only for steps that actually issued a decode
-    # stream (a drain step whose live problems all retire or post empty
-    # demand moves no tokens, so counting it would understate the batch
-    # fill the decode kernel really saw).
-    problems_per_step: List[int] = field(default_factory=list)
+    # total branch demand posted per global step, only for steps that
+    # actually issued a decode stream (a drain step whose live problems
+    # all retire or post empty demand moves no tokens, so counting it
+    # would understate the batch fill the decode kernel really saw)
     demand_per_step: List[int] = field(default_factory=list)
-    # retirements routed through a backend lacking ``finish_problem``
-    # (fine for synthetic backends; a red flag for engine backends)
-    finish_hook_missing: int = 0
 
     def mean_occupancy(self) -> float:
         """Mean branch demand per decode-issuing global step (the
@@ -1193,6 +1187,7 @@ class SweepScheduler:
             budget -= pp + first_need
         return out
 
+    @spanned("loop.admit")
     def _admit(self) -> None:
         room = self.max_live - len(self.live) - len(self.parked)
         if room <= 0 or not self._queue:
@@ -1246,6 +1241,7 @@ class SweepScheduler:
                 self.stats.max_reserved_pages, self._reserved.total())
 
     # -- retirement ----------------------------------------------------
+    @spanned("loop.retire")
     def _retire(self, idx: int) -> None:
         st = self.live.pop(idx)
         self.results[idx] = st.result()
@@ -1257,7 +1253,7 @@ class SweepScheduler:
             self._reserved.release(idx)
         self._prompt_pages.pop(idx, None)
         self._peak.pop(idx, None)
-        _release_problem(self.backend, st.tree, self.stats)
+        _release_problem(self.backend, st.tree)
 
     # -- difficulty-adaptive width -------------------------------------
     def _adapt(self, idx: int, st: SearchState) -> None:
@@ -1298,11 +1294,13 @@ class SweepScheduler:
         Returns True while there is work left (live, parked or
         queued)."""
         if self._mem:
-            self._resume_parked()
+            with span("loop.pressure"):
+                self._resume_parked()
         self._admit()
         if self._mem:
-            self._update_peaks()
-            self._handle_pressure()
+            with span("loop.pressure"):
+                self._update_peaks()
+                self._handle_pressure()
         # 1. demand: retire problems that have nothing left to do
         reqs: List[Tuple[SearchTree, List[Tuple[int, int]]]] = []
         states: List[Tuple[int, SearchState]] = []
@@ -1318,7 +1316,6 @@ class SweepScheduler:
         if not reqs:
             return bool(self.live or self.parked or self._queue)
         self.stats.global_steps += 1
-        self.stats.problems_per_step.append(len(reqs))
         posted = sum(n for _, lc in reqs for _, n in lc)
         # 2. ONE expansion stream over every problem's branches
         kid_groups = _expand_multi(self.backend, reqs)

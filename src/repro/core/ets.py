@@ -27,6 +27,8 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs import span
+
 from .clustering import cluster_embeddings
 from .ilp import SelectionProblem, SelectionResult, solve
 from .rebase import rebase_reweight, rebase_weights
@@ -69,8 +71,9 @@ def ets_prune(tree: SearchTree, candidates: Sequence[int],
     n_clusters = 0
     if cfg.use_clustering and cfg.lambda_d > 0 and embeddings is not None \
             and L > 1:
-        clusters = cluster_embeddings(np.asarray(embeddings),
-                                      cfg.cluster_threshold)
+        with span("ets.cluster"):
+            clusters = cluster_embeddings(np.asarray(embeddings),
+                                          cfg.cluster_threshold)
         n_clusters = len(set(clusters.tolist()))
 
     node_weights = None
@@ -87,7 +90,8 @@ def ets_prune(tree: SearchTree, candidates: Sequence[int],
         lambda_b=cfg.lambda_b,
         lambda_d=cfg.lambda_d if clusters is not None else 0.0,
     )
-    res = solve(prob, cfg.solver)
+    with span("ets.ilp"):
+        res = solve(prob, cfg.solver)
     counts = rebase_reweight(rewards, res.selected, n_total,
                              cfg.rebase_temperature)
     return ETSStep(selected=res.selected, counts=counts, weights_all=W,

@@ -49,10 +49,13 @@ from __future__ import annotations
 
 import json
 import math
+import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
+
+from repro.obs import span, spanned
 
 from .controllers import (AdaptiveConfig, SearchConfig, SearchResult,
                           SweepScheduler, _embed_multi, _expand_multi,
@@ -123,25 +126,35 @@ def load_trace(path: str) -> List[Request]:
 
 @dataclass
 class SLOTracker:
-    """Per-request lifecycle stamps on the virtual clock."""
+    """Per-request lifecycle stamps on the virtual clock, and beside
+    them the host clock (``time.perf_counter``) at the same events:
+    when the loop was handed the request (construction or ``submit``),
+    admitted it and finished it.  Latencies are computed on the virtual
+    clock; the host stamps give the same intervals in wall time."""
     arrivals: Dict[int, float] = field(default_factory=dict)
     admitted: Dict[int, float] = field(default_factory=dict)
     finished: Dict[int, float] = field(default_factory=dict)
     deadlines: Dict[int, float] = field(default_factory=dict)
     priorities: Dict[int, int] = field(default_factory=dict)
+    submitted_wall: Dict[int, float] = field(default_factory=dict)
+    admitted_wall: Dict[int, float] = field(default_factory=dict)
+    finished_wall: Dict[int, float] = field(default_factory=dict)
 
     def note_arrival(self, idx: int, t: float, priority: int = 0,
                      deadline: Optional[float] = None) -> None:
         self.arrivals[idx] = float(t)
+        self.submitted_wall[idx] = time.perf_counter()
         self.priorities[idx] = int(priority)
         if deadline is not None:
             self.deadlines[idx] = float(deadline)
 
     def note_admit(self, idx: int, t: float) -> None:
         self.admitted[idx] = float(t)
+        self.admitted_wall[idx] = time.perf_counter()
 
     def note_finish(self, idx: int, t: float) -> None:
         self.finished[idx] = float(t)
+        self.finished_wall[idx] = time.perf_counter()
 
     def tta(self) -> Dict[int, float]:
         """Time-to-answer per finished request."""
@@ -356,6 +369,7 @@ class ServingLoop(SweepScheduler):
             self.slo.note_finish(idx, self.clock)
 
     # -- ticks ---------------------------------------------------------
+    @spanned("loop.tick")
     def tick(self) -> bool:
         """Advance the server by one scheduling quantum.  Returns True
         while any request is pending, queued, or in flight."""
@@ -398,11 +412,13 @@ class ServingLoop(SweepScheduler):
         barrier; token-level refill when the backend supports it."""
         self._retired_this_tick = []
         if self._mem:
-            self._resume_parked()
+            with span("loop.pressure"):
+                self._resume_parked()
         self._admit()
         if self._mem:
-            self._update_peaks()
-            self._handle_pressure()
+            with span("loop.pressure"):
+                self._update_peaks()
+                self._handle_pressure()
         if self._rowlevel:
             self._pump_stream()
         else:
@@ -416,39 +432,40 @@ class ServingLoop(SweepScheduler):
         stream = self._stream
         if stream is None:
             stream = self._stream = self.backend.open_stream()
-        # 1. every demand-phase problem posts its step's decode rows
-        #    (branched + keyed now; seated as slots free up)
-        for idx in sorted(self.live):
-            st = self.live[idx]
-            if idx in self._tickets or st.phase != "demand":
-                continue
-            self._adapt(idx, st)
-            lc = st.demand()
-            if lc is None:
-                self._retire(idx)
-                continue
-            ticket = self.backend.expand_begin(st.tree, lc)
-            if not ticket.branches:
-                st.note_children([])    # empty expansion ends the search
-                assert st.finished
-                self._retire(idx)
-                continue
-            self._tickets[idx] = ticket
-            self._waiting[idx] = set(ticket.branches)
-            for row, bid in enumerate(ticket.branches):
-                self._owner[bid] = idx
-                self._jobq.append((idx, bid, row))
-        # 2. refill free slots, highest priority first (row keys make
-        #    seat timing invisible to the sampled streams)
-        if self._jobq and stream.n_free:
-            self._jobq.sort(key=lambda e: (
-                -self._priority.get(e[0], 0), e[0], e[2]))
-            take, self._jobq = (self._jobq[:stream.n_free],
-                                self._jobq[stream.n_free:])
-            keys = jnp.stack([self._tickets[i].row_keys[row]
-                              for i, _, row in take])
-            stream.add([bid for _, bid, _ in take], keys,
-                       self.backend.stream_budget())
+        with span("loop.seat"):
+            # 1. every demand-phase problem posts its step's decode rows
+            #    (branched + keyed now; seated as slots free up)
+            for idx in sorted(self.live):
+                st = self.live[idx]
+                if idx in self._tickets or st.phase != "demand":
+                    continue
+                self._adapt(idx, st)
+                lc = st.demand()
+                if lc is None:
+                    self._retire(idx)
+                    continue
+                ticket = self.backend.expand_begin(st.tree, lc)
+                if not ticket.branches:
+                    st.note_children([])   # empty expansion ends the search
+                    assert st.finished
+                    self._retire(idx)
+                    continue
+                self._tickets[idx] = ticket
+                self._waiting[idx] = set(ticket.branches)
+                for row, bid in enumerate(ticket.branches):
+                    self._owner[bid] = idx
+                    self._jobq.append((idx, bid, row))
+            # 2. refill free slots, highest priority first (row keys make
+            #    seat timing invisible to the sampled streams)
+            if self._jobq and stream.n_free:
+                self._jobq.sort(key=lambda e: (
+                    -self._priority.get(e[0], 0), e[0], e[2]))
+                take, self._jobq = (self._jobq[:stream.n_free],
+                                    self._jobq[stream.n_free:])
+                keys = jnp.stack([self._tickets[i].row_keys[row]
+                                  for i, _, row in take])
+                stream.add([bid for _, bid, _ in take], keys,
+                           self.backend.stream_budget())
         # 3. ONE lock-step iteration over the seated rows
         if not stream.live:
             return
@@ -693,6 +710,9 @@ class ReplicaServingLoop:
             out.finished.update(lp.slo.finished)
             out.deadlines.update(lp.slo.deadlines)
             out.priorities.update(lp.slo.priorities)
+            out.submitted_wall.update(lp.slo.submitted_wall)
+            out.admitted_wall.update(lp.slo.admitted_wall)
+            out.finished_wall.update(lp.slo.finished_wall)
         return out
 
     @property

@@ -125,6 +125,7 @@ from repro.kvcache.pool import (PendingGather, PendingStateGather,
 # not import serving); re-exported here for the engine-side callers
 from repro.kvcache.pool import pow2_bucket  # noqa: F401  (re-export)
 from repro.kernels.ref import tree_attention_ref
+from repro.obs import span, spanned
 from .runtimes import (DecodeCtx, PrefillCtx, build_runtimes,
                        collect_state_specs, total_kv_layers)
 
@@ -1083,6 +1084,7 @@ class DecodeStream:
         self._slot_seq[j] = None
         self._budget.pop(i, None)
 
+    @spanned("engine.decode")
     def step(self) -> List[int]:
         """Run ONE lock-step iteration over the occupied slots.
 
@@ -1098,82 +1100,93 @@ class DecodeStream:
         if not live:
             return []
         eng.n_decode_steps += 1
-        # reserve one slot per live sequence (may CoW)
-        copy_ops = []
-        for i in live:
-            copy_ops += eng.alloc.append_tokens(i, 1)
-        eng.pool.copy_pages(copy_ops)
+        with span("engine.decode.reserve"):
+            # reserve one slot per live sequence (may CoW)
+            copy_ops = []
+            for i in live:
+                copy_ops += eng.alloc.append_tokens(i, 1)
+            eng.pool.copy_pages(copy_ops)
 
-        B = ecfg.max_batch
-        T = eng.max_pages_per_seq
-        tok = np.zeros(B, np.int32)
-        bt = None if tree_mode else np.full((B, T), -1, np.int32)
-        lens = np.zeros(B, np.int32)
-        pages = np.full(B, eng.dump_page, np.int32)   # inactive -> dump
-        slots = np.zeros(B, np.int32)
-        act = np.zeros(B, bool)
-        rows: List[Optional[int]] = [None] * B
-        for j, i in enumerate(self._slot_seq):
-            if i is None:
-                continue
-            h = eng.alloc.seqs[i]
-            tok[j] = eng.tokens[i][-1]
-            if not tree_mode:
-                bt[j, :len(h.block_table)] = h.block_table
-            pos = h.length - 1              # slot reserved for the new token
-            lens[j] = pos
-            pages[j] = h.block_table[pos // ecfg.page_size]
-            slots[j] = pos % ecfg.page_size
-            act[j] = True
-            rows[j] = i
+        with span("engine.decode.metadata"):
+            B = ecfg.max_batch
+            T = eng.max_pages_per_seq
+            tok = np.zeros(B, np.int32)
+            bt = None if tree_mode else np.full((B, T), -1, np.int32)
+            lens = np.zeros(B, np.int32)
+            pages = np.full(B, eng.dump_page, np.int32)   # inactive -> dump
+            slots = np.zeros(B, np.int32)
+            act = np.zeros(B, bool)
+            rows: List[Optional[int]] = [None] * B
+            for j, i in enumerate(self._slot_seq):
+                if i is None:
+                    continue
+                h = eng.alloc.seqs[i]
+                tok[j] = eng.tokens[i][-1]
+                if not tree_mode:
+                    bt[j, :len(h.block_table)] = h.block_table
+                pos = h.length - 1          # slot reserved for the new token
+                lens[j] = pos
+                pages[j] = h.block_table[pos // ecfg.page_size]
+                slots[j] = pos % ecfg.page_size
+                act[j] = True
+                rows[j] = i
 
-        srows = eng._state_rows(rows, B)
-        if tree_mode:
-            meta = eng.alloc.tree_metadata(rows, pad_page=eng.dump_page)
-            eng._count_streamed_pages(live, meta.n_unique, meta.n_logical)
-            # rows shard batch->data; the unique-page metadata spans the
-            # whole tree (no batch axis) and stays replicated
-            logits, eng.pool.k, eng.pool.v, new_state = \
-                eng._tree_decode_fn(
-                    eng.params, eng._put_rows(tok), eng._put_rows(lens),
-                    eng._put_rows(pages), eng._put_rows(slots),
-                    eng._put_rows(act), eng._put_repl(meta.page_list),
-                    eng._put_repl(meta.page_mask),
-                    eng._put_repl(meta.page_lens), eng._put_rows(srows),
-                    eng.pool.k, eng.pool.v, eng._state_in())
-        else:
-            # paged reads stream every page of every live row
-            n_logical = sum(len(eng.alloc.seqs[i].block_table)
-                            for i in live)
-            eng._count_streamed_pages(live, n_logical, n_logical)
-            logits, eng.pool.k, eng.pool.v, new_state = eng._decode_fn(
-                eng.params, eng._put_rows(tok), eng._put_repl(bt),
-                eng._put_rows(lens), eng._put_rows(pages),
-                eng._put_rows(slots), eng._put_rows(act),
-                eng._put_rows(srows), eng.pool.k, eng.pool.v,
-                eng._state_in())
-        eng._state_out(new_state)
-        if ecfg.trace_logits:
-            eng.logits_trace.append(np.asarray(logits))
-        # advance every slot's own key chain (freed slots' keys advance
-        # too, but their samples are never consumed — a row's stream
-        # depends only on how many iterations it was live for)
-        pair = _split_rows(self._keys)
-        self._keys, subs = pair[:, 0], pair[:, 1]
-        new = np.asarray(sample_tokens_rowwise(subs, logits,
-                                               self.temperature))
-        finished: List[int] = []
-        for j, i in enumerate(self._slot_seq):
-            if i is None:
-                continue
-            t = int(new[j])
-            eng.tokens[i].append(t)
-            self.out[i].append(t)
-            eng.n_decoded_tokens += 1
-            self._budget[i] -= 1
-            if t in self.stop or len(eng.tokens[i]) >= ecfg.max_seq_len \
-                    or self._budget[i] <= 0:
-                finished.append(i)
-        for i in finished:
-            self._free_slot(i)
+            srows = eng._state_rows(rows, B)
+            if tree_mode:
+                meta = eng.alloc.tree_metadata(rows, pad_page=eng.dump_page)
+                eng._count_streamed_pages(live, meta.n_unique,
+                                          meta.n_logical)
+            else:
+                # paged reads stream every page of every live row
+                n_logical = sum(len(eng.alloc.seqs[i].block_table)
+                                for i in live)
+                eng._count_streamed_pages(live, n_logical, n_logical)
+
+        with span("engine.decode.launch"):
+            if tree_mode:
+                # rows shard batch->data; the unique-page metadata spans
+                # the whole tree (no batch axis) and stays replicated
+                logits, eng.pool.k, eng.pool.v, new_state = \
+                    eng._tree_decode_fn(
+                        eng.params, eng._put_rows(tok), eng._put_rows(lens),
+                        eng._put_rows(pages), eng._put_rows(slots),
+                        eng._put_rows(act), eng._put_repl(meta.page_list),
+                        eng._put_repl(meta.page_mask),
+                        eng._put_repl(meta.page_lens), eng._put_rows(srows),
+                        eng.pool.k, eng.pool.v, eng._state_in())
+            else:
+                logits, eng.pool.k, eng.pool.v, new_state = eng._decode_fn(
+                    eng.params, eng._put_rows(tok), eng._put_repl(bt),
+                    eng._put_rows(lens), eng._put_rows(pages),
+                    eng._put_rows(slots), eng._put_rows(act),
+                    eng._put_rows(srows), eng.pool.k, eng.pool.v,
+                    eng._state_in())
+            eng._state_out(new_state)
+            if ecfg.trace_logits:
+                eng.logits_trace.append(np.asarray(logits))
+            # advance every slot's own key chain (freed slots' keys
+            # advance too, but their samples are never consumed — a
+            # row's stream depends only on how many iterations it was
+            # live for)
+            pair = _split_rows(self._keys)
+            self._keys, subs = pair[:, 0], pair[:, 1]
+            sampled = sample_tokens_rowwise(subs, logits, self.temperature)
+        with span("engine.decode.wait"):
+            new = np.asarray(sampled)
+        with span("engine.decode.commit"):
+            finished: List[int] = []
+            for j, i in enumerate(self._slot_seq):
+                if i is None:
+                    continue
+                t = int(new[j])
+                eng.tokens[i].append(t)
+                self.out[i].append(t)
+                eng.n_decoded_tokens += 1
+                self._budget[i] -= 1
+                if t in self.stop \
+                        or len(eng.tokens[i]) >= ecfg.max_seq_len \
+                        or self._budget[i] <= 0:
+                    finished.append(i)
+            for i in finished:
+                self._free_slot(i)
         return finished

@@ -94,6 +94,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.tree import SearchTree
+from repro.obs import spanned
 
 from .engine import PagedEngine, pow2_bucket as _bucket
 
@@ -201,6 +202,11 @@ class LMBackend:
         # O(log max_len), not O(distinct lengths).
         self.score_traces = 0
         self.embed_traces = 0
+        # PRM work of score_multi: rows scored, their true token counts,
+        # and the tokens of the padded (rows, length) buckets they ran in
+        self.n_scored_rows = 0
+        self.n_scored_tokens = 0
+        self.n_scored_padded_tokens = 0
 
         def score_batch(p, toks, positions, lengths):
             self.score_traces += 1      # trace-time side effect
@@ -231,6 +237,7 @@ class LMBackend:
     def start(self, prompt_tokens: Sequence[int]) -> SearchTree:
         return self.start_many([prompt_tokens])[0]
 
+    @spanned("backend.prefill")
     def start_many(self, prompts: Sequence[Sequence[int]]
                    ) -> List[SearchTree]:
         """Prefill a whole problem sweep in one batched flash stream.
@@ -302,6 +309,7 @@ class LMBackend:
     # keys make the schedule irrelevant: a branch's stream depends only
     # on its own key and logits, so both drivers are bit-identical.
 
+    @spanned("backend.expand_begin")
     def expand_begin(self, tree: SearchTree,
                      leaf_counts: Sequence[Tuple[int, int]]
                      ) -> "ExpandTicket":
@@ -329,6 +337,7 @@ class LMBackend:
         return ExpandTicket(tree=tree, plan=plan, branches=branches,
                             row_keys=row_keys)
 
+    @spanned("backend.expand_finish")
     def expand_finish(self, ticket: "ExpandTicket",
                       outs: Dict[int, List[int]]) -> List[int]:
         """Turn a ticket's decoded streams (``outs``: seq id -> step
@@ -406,6 +415,7 @@ class LMBackend:
         """One padded-bucket PRM call for every candidate of the step."""
         return self.score_multi([(tree, nodes)])[0]
 
+    @spanned("backend.score")
     def score_multi(self, reqs: Sequence[Tuple[SearchTree, Sequence[int]]]
                     ) -> List[List[float]]:
         """ONE padded-bucket PRM call covering every problem's
@@ -419,6 +429,9 @@ class LMBackend:
         if not seqs:
             return [[] for _ in reqs]
         toks, pos, lengths = _pad_bucket(seqs)
+        self.n_scored_rows += len(seqs)
+        self.n_scored_tokens += int(lengths[:len(seqs)].sum())
+        self.n_scored_padded_tokens += toks.size
         r = self._score_batch_fn(self.prm_params, jnp.asarray(toks),
                                  jnp.asarray(pos), jnp.asarray(lengths))
         flat = [float(x) for x in np.asarray(r)[:len(seqs)]]
@@ -438,6 +451,7 @@ class LMBackend:
         attention (positions == -1) and of the mean pool."""
         return self.embed_multi([(tree, nodes)])[0]
 
+    @spanned("backend.embed")
     def embed_multi(self, reqs: Sequence[Tuple[SearchTree, Sequence[int]]]
                     ) -> List[np.ndarray]:
         """ONE bucketed encoder call covering every problem's nodes."""
@@ -474,6 +488,7 @@ class LMBackend:
         # not O(every sequence in the allocator), per step
         return fn(ns, seq_ids=sorted(self._ns_seqs.get(ns, ())))
 
+    @spanned("backend.release")
     def on_step(self, tree: SearchTree, live: Sequence[int]) -> None:
         """Free engine sequences of pruned/finished leaves; sample stats.
 
