@@ -130,9 +130,15 @@ from .runtimes import (DecodeCtx, PrefillCtx, build_runtimes,
                        collect_state_specs, total_kv_layers)
 
 
-# One jitted split per decode iteration advances every row's key chain
-# in lock-step (rows are independent: chain position == live iterations).
-_split_rows = jax.jit(jax.vmap(lambda k: jax.random.split(k, 2)))
+@jax.jit
+def _advance_keys(keys):
+    """Advance every row's key chain one link, in one program.
+
+    Returns ``(next_keys, subkeys)``: per row the two halves of
+    ``jax.random.split(k, 2)``, bit for bit.  Rows are independent, so
+    a chain's position is the number of iterations its row was live."""
+    pair = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
+    return pair[:, 0], pair[:, 1]
 
 
 @dataclass
@@ -280,6 +286,10 @@ class PagedEngine:
         # tokens ingested by them (benchmarks/table2 prefill tok/s)
         self.n_prefill_calls = 0
         self.n_prefill_tokens = 0
+        # Python-level host->device transfers of step operands
+        # (``_put_rows`` / ``_put_repl``): 0 without a mesh, where the
+        # jitted step's dispatch commits the host arrays itself
+        self.n_host_puts = 0
         # swap accounting (page demotion under memory pressure): pages
         # moved device->host (swap-out) and host->device (swap-in), and
         # the demotion calls that moved them.  Reconciles with the
@@ -518,11 +528,12 @@ class PagedEngine:
         pages/slots, active mask — anything whose axis 0 is the row
         grid) with the serve policy's batch->``data`` sharding.  The
         per-shape NamedSharding is cached, so fallback recording fires
-        once per shape, not once per step.  Without a mesh this is
-        exactly the historical ``jnp.asarray`` — same bits, same jit
-        signatures."""
+        once per shape, not once per step.  Without a mesh the host
+        array itself is returned: the jitted step's own dispatch commits
+        it to the default device, with no Python-level transfer (same
+        bits, same compiled program)."""
         if self.mesh is None:
-            return jnp.asarray(arr)
+            return arr
         shape = np.shape(arr)
         shd = self._row_shd_cache.get(shape)
         if shd is None:
@@ -532,6 +543,7 @@ class PagedEngine:
                 self.mesh, engine_batch_spec(self.mesh, shape,
                                              record=self.shard_fallbacks))
             self._row_shd_cache[shape] = shd
+        self.n_host_puts += 1
         return jax.device_put(np.asarray(arr), shd)
 
     def _put_repl(self, arr):
@@ -539,14 +551,16 @@ class PagedEngine:
         tables and the tree step's unique-page metadata (page lists,
         descendant bitmaps, page lengths) index the *whole* pool, so
         every shard needs all of them — the mesh-obliviousness contract
-        of the allocator's tree-metadata derivation."""
+        of the allocator's tree-metadata derivation.  Without a mesh
+        the host array itself, as in ``_put_rows``."""
         if self.mesh is None:
-            return jnp.asarray(arr)
+            return arr
         shd = self._row_shd_cache.get(("repl",))
         if shd is None:
             from jax.sharding import NamedSharding, PartitionSpec
             shd = NamedSharding(self.mesh, PartitionSpec())
             self._row_shd_cache[("repl",)] = shd
+        self.n_host_puts += 1
         return jax.device_put(np.asarray(arr), shd)
 
     # ------------------------------------------------------------------
@@ -730,8 +744,8 @@ class PagedEngine:
                 self._streamed_prefill_fn(
                     self.params, self._put_rows(tok), self._put_rows(pos),
                     self._put_rows(pages), self._put_rows(slots),
-                    jnp.asarray(np.int32(m)), tbl_j,
-                    jnp.asarray(np.int32(s0)), self._put_rows(srows),
+                    np.int32(m), tbl_j,
+                    np.int32(s0), self._put_rows(srows),
                     self.pool.k, self.pool.v, self._state_in())
             self._state_out(new_state)
         if self.ecfg.trace_logits:
@@ -906,6 +920,7 @@ class PagedEngine:
         self.n_decoded_tokens = 0
         self.n_prefill_calls = 0
         self.n_prefill_tokens = 0
+        self.n_host_puts = 0
         self.swapped_out_pages = 0
         self.swapped_in_pages = 0
         self.n_swap_outs = 0
@@ -1168,8 +1183,7 @@ class DecodeStream:
             # advance too, but their samples are never consumed — a
             # row's stream depends only on how many iterations it was
             # live for)
-            pair = _split_rows(self._keys)
-            self._keys, subs = pair[:, 0], pair[:, 1]
+            self._keys, subs = _advance_keys(self._keys)
             sampled = sample_tokens_rowwise(subs, logits, self.temperature)
         with span("engine.decode.wait"):
             new = np.asarray(sampled)

@@ -126,6 +126,9 @@ def test_one_device_mesh_bit_identical(tiny_models, attention):
     assert engine.pool.sharding is not None
     assert engine.pool.k.sharding.mesh.size == 1
     assert engine.shard_fallbacks == []
+    # the mesh engine puts each operand itself; the mesh-less one none
+    assert engine.n_host_puts > 0
+    assert base.engine.n_host_puts == 0
 
 
 def test_replica_sweep_lm_bit_identical(tiny_models):
@@ -274,17 +277,26 @@ def probe(mesh):
         attention=sys.argv[1], trace_logits=True, mesh=mesh))
     roots = eng.prefill_many([list(range(4, 4 + n)) for n in (17, 9)])
     kids = [k for r in roots for k in eng.branch(r, 2)]
+    puts = eng.n_host_puts
     out = eng.decode(kids, 5, temperature=0.0,
                      row_keys=jax.random.split(jax.random.key(1), len(kids)))
     eng.alloc.check_invariants()
     devs = len(eng.pool.k.devices())
-    return [out[k] for k in kids], [np.asarray(a) for a in eng.logits_trace], devs
+    puts = (eng.n_host_puts - puts) / eng.n_decode_steps
+    return ([out[k] for k in kids], [np.asarray(a) for a in eng.logits_trace],
+            devs, puts, eng)
 
-want, want_logits, _ = probe(None)
-got, got_logits, devs = probe(make_host_mesh(model=4))
+want, want_logits, _, want_puts, _ = probe(None)
+got, got_logits, devs, puts, eng = probe(make_host_mesh(model=4))
 gap = max(float(abs(a - b).max()) for a, b in zip(got_logits, want_logits))
+n = eng.n_host_puts
+row = eng._put_rows(np.zeros(8, np.int32))
 print(json.dumps({"devices": jax.device_count(), "pool_devices": devs,
-                  "same_tokens": got == want, "gap": gap}))
+                  "same_tokens": got == want, "gap": gap,
+                  "puts_per_iter": [want_puts, puts],
+                  "row_put": [isinstance(row, jax.Array),
+                              len(row.sharding.device_set),
+                              eng.n_host_puts - n]}))
 """
 
 
@@ -315,6 +327,11 @@ def test_model4_mesh_engine_matches_meshless(attention):
     assert res["devices"] == 4 and res["pool_devices"] == 4, res
     assert res["same_tokens"], res
     assert res["gap"] <= 1e-5, res
+    # the mesh engine still commits each step operand itself, one
+    # counted put apiece (tree: 6 per-row + 3 page-metadata operands;
+    # paged: 6 per-row + the block tables); without a mesh none
+    assert res["puts_per_iter"] == [0, {"tree": 9, "paged": 7}[attention]]
+    assert res["row_put"] == [True, 4, 1], res
 
 
 _REPLICA_PROBE = """
