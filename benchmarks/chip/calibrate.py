@@ -52,7 +52,7 @@ def main(argv=None):
     lows, highs = {}, {}
     for i, seed in enumerate(args.seeds):
         if i:
-            H.reseed(stack, seed)
+            H.reseed(cell, stack, seed)
         H.clear(stack)
         rec.reset()
         traffic = TF.generate(cell.mix, seed, args.seconds, c["vocab_size"],

@@ -5,10 +5,13 @@ reference, and reduce counters and the trace to metrics.
 
 A cell is found by its name in ``BENCHMARK.json``; everything that
 belongs to it sits in files of its own under ``benchmarks/chip``:
-``configs/<config>.json`` (sizes, PRM, embedder, engine pool),
-``traffic/<mix>.json`` (see ``traffic.py``), ``metrics/<metric>.py``
-(one ``read(ctx)`` per per-layer metric) and ``limits/<cell>.json``
-(the correctness limits, set from measured readings).
+``configs/<config>.json`` (sizes, PRM, embedder, engine settings, and
+the model family under ``"family"``, ``"dense"`` where it names none),
+``families/<family>.py`` (what differs between model families: see
+``families/dense.py``), ``traffic/<mix>.json`` (see ``traffic.py``),
+``metrics/<metric>.py`` (one ``read(ctx)`` per per-layer metric) and
+``limits/<cell>.json`` (the correctness limits, set from measured
+readings).
 
 The window drives ``ServingLoop.tick()`` over one ``LMBackend`` on one
 ``PagedEngine`` with tree attention.  The harness owns the clock: the
@@ -51,6 +54,7 @@ class Cell:
     end_to_end: List[dict]
     per_layer: List[dict]
     limits: dict
+    family: Any             # the module families/<family>.py
 
 
 def _json(path):
@@ -81,17 +85,26 @@ def load_cell(root: str, name: str) -> Cell:
     lim_path = os.path.join(root, DATA, "limits", name + ".json")
     limits = _json(lim_path) if os.path.exists(lim_path) else {}
     return Cell(name, root, config, mix, int(w["chips"]), e2e, per_layer,
-                limits)
+                limits, family(root, config.get("family", "dense")))
+
+
+def _module(root: str, sub: str, name: str, prefix: str):
+    path = os.path.join(root, DATA, sub, name + ".py")
+    spec = importlib.util.spec_from_file_location(
+        prefix + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def metric_reader(root: str, metric: str):
     """``read(ctx)`` of ``metrics/<metric>.py``."""
-    path = os.path.join(root, DATA, "metrics", metric + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(root, "metrics", metric, "bench_metric_").read
+
+
+def family(root: str, name: str):
+    """The module ``families/<name>.py``."""
+    return _module(root, "families", name, "bench_family_")
 
 
 def peaks(root: str, device_kind: str) -> dict:
@@ -118,22 +131,6 @@ def prm_config(config: dict) -> dict:
     return out
 
 
-def model_config(c: dict, name: str, *, encoder: bool = False):
-    from repro.configs.base import ModelConfig
-    heads = c["num_attention_heads"]
-    return ModelConfig(
-        name=name, arch_type="encoder" if encoder else "dense",
-        n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        n_heads=heads, n_kv_heads=c.get("num_key_value_heads", heads),
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        head_dim=c.get("head_dim", 0),
-        rope_theta=float(c.get("rope_theta", 10000.0)),
-        norm_eps=float(c.get("rms_norm_eps", 1e-5)),
-        act="swiglu" if c.get("hidden_act", "silu") == "silu" else "gelu",
-        causal=not encoder, tie_embeddings=False,
-        dtype=c.get("torch_dtype", "bfloat16"))
-
-
 def max_seq_len(cell: Cell) -> int:
     m = cell.mix
     ps = cell.config["engine"]["page_size"]
@@ -154,25 +151,46 @@ class Stack:
     params: tuple          # generator, PRM, embedder weights
 
 
+ENGINE_KEYS = ("n_pages", "page_size", "n_state_pages")
+
+
+def engine_config(cell: Cell, *, use_kernel: bool):
+    """The ``EngineConfig``: the pool sizes the configuration's
+    ``engine`` object names, the batch and sequence length the mix
+    sets, and the path the harness warms and times (tree attention,
+    flash prefill, ``use_kernel``).  A configuration naming any other
+    field is refused, since it would change that path or the programs
+    the warm-up covers."""
+    from repro.serving.engine import EngineConfig
+    m, named = cell.mix, cell.config["engine"]
+    refused = sorted(set(named) - set(ENGINE_KEYS))
+    if refused:
+        raise ValueError(
+            f"{cell.config['name']}: the engine object names {refused}; "
+            f"a configuration sets only {list(ENGINE_KEYS)}, and the "
+            f"harness and the traffic mix set the rest")
+    return EngineConfig(
+        **named, max_batch=m["max_live"] * m["width"],
+        max_seq_len=max_seq_len(cell), attention="tree", use_kernel=use_kernel)
+
+
 def build(cell: Cell, seed: int, *, use_kernel: bool) -> Stack:
     from repro.core import ETSConfig, SearchConfig
     from repro.models.model import build_model
-    from repro.serving.engine import EngineConfig, PagedEngine
+    from repro.serving.engine import PagedEngine
     from repro.serving.search_backend import BackendConfig, LMBackend
 
-    c, m = cell.config, cell.mix
-    lm = build_model(model_config(generator_config(c), c["name"]),
+    c, m, fam = cell.config, cell.mix, cell.family
+    n = max_seq_len(cell)
+    lm = build_model(fam.model_config(generator_config(c), c["name"], n),
                      remat=False)
-    prm = build_model(model_config(prm_config(c), c["name"] + "-prm"),
+    prm = build_model(fam.model_config(prm_config(c), c["name"] + "-prm", n),
                       with_value_head=True, remat=False)
-    emb = build_model(model_config(c["embedder"], "embedder", encoder=True),
-                      remat=False)
-    params = make_weights((lm, prm, emb), seed)
-    ecfg = EngineConfig(
-        n_pages=c["engine"]["n_pages"], page_size=c["engine"]["page_size"],
-        max_batch=m["max_live"] * m["width"], max_seq_len=max_seq_len(cell),
-        attention="tree", use_kernel=use_kernel)
-    engine = PagedEngine(lm, params[0], ecfg)
+    emb = build_model(family(cell.root, "dense").model_config(
+        c["embedder"], "embedder", n, encoder=True), remat=False)
+    params = make_weights((lm, prm, emb), seed, fam)
+    engine = PagedEngine(lm, params[0],
+                         engine_config(cell, use_kernel=use_kernel))
     backend = LMBackend(
         engine, prm, params[1], emb, params[2],
         BackendConfig(step_token=c["step_token"], eos_token=c["eos_token"],
@@ -185,19 +203,21 @@ def build(cell: Cell, seed: int, *, use_kernel: bool) -> Stack:
     return Stack(engine, backend, scfg, (lm, prm, emb), params)
 
 
-def make_weights(models, seed: int):
-    """Generator, PRM and embedder weights from ``seed``.  The PRM is
-    served for its reward only, so its output head is not made."""
+def make_weights(models, seed: int, fam):
+    """Generator, PRM and embedder weights from ``seed``, the first two
+    by the rules of the family ``fam``.  The PRM is served for its
+    reward only, so its output head is not made."""
     return tuple(W.make(models, TF.sub_seed(seed, 1),
-                        skip=((), ("lm_head",), ())))
+                        skip=((), ("lm_head",), ()),
+                        rules=(fam.leaf_init, fam.leaf_init, None)))
 
 
-def reseed(stack: Stack, seed: int) -> None:
+def reseed(cell: Cell, stack: Stack, seed: int) -> None:
     """New weights and sampling chains from ``seed`` on the same
     compiled stack (calibration runs many seeds in one process)."""
     stack.engine.params = stack.backend.prm_params = None
     stack.backend.embed_params = stack.params = None
-    gen, prm, emb = make_weights(stack.models, seed)
+    gen, prm, emb = make_weights(stack.models, seed, cell.family)
     stack.engine.params = gen
     stack.backend.prm_params = prm
     stack.backend.embed_params = emb
@@ -314,7 +334,9 @@ def _warm_score_embed(cell: Cell, stack: Stack) -> Dict[str, int]:
 
 
 def _warm_swap(cell: Cell, stack: Stack) -> Dict[str, int]:
-    """Page demotion and restore at every page bucket."""
+    """Page demotion and restore at every page bucket, and, where the
+    engine keeps recurrent state, the state pages' demotion, zeroing
+    at allocation and restore at every state-page bucket."""
     eng = stack.engine
     shape, n = eng.pool.k.shape, 0
     for pages in _pow2s(1, eng.ecfg.n_pages):
@@ -323,7 +345,19 @@ def _warm_swap(cell: Cell, stack: Stack) -> Dict[str, int]:
         eng.pool.scatter_pages([eng.dump_page] * pages, host, host,
                                dump_page=eng.dump_page)
         n += 1
-    return {"swap": n}
+    done = {"swap": n}
+    st = eng.state
+    if st is not None:
+        done["state_swap"] = 0
+        for pages in _pow2s(1, st.dump_page, 1):
+            dump = [st.dump_page] * pages
+            st.gather_pages_async(dump)
+            st.zero(dump)
+            st.scatter_pages(dump, {
+                k: np.zeros((L, pages) + tuple(per_page), dtype)
+                for k, (L, per_page, dtype) in st.specs.items()})
+            done["state_swap"] += 1
+    return done
 
 
 def _warm_stream(cell: Cell, stack: Stack) -> Dict[str, int]:
@@ -442,12 +476,13 @@ class Recorder:
         be.expand_begin = span("expand_begin", be.expand_begin)
         be.expand_finish = span("expand_finish", be.expand_finish)
 
-    def flops(self, dims: Dict[str, F.Dims]) -> int:
-        """Required operations of everything recorded."""
+    def flops(self, dims: Dict[str, Any], fam) -> int:
+        """Required operations of everything recorded, the generator's
+        and the PRM's counted by their family ``fam``."""
         g, p, e = dims["generator"], dims["prm"], dims["embedder"]
-        total = F.decode(g, self.decode_rows, self.decode_keys_sum)
-        total += sum(F.prefill(g, n) for n in self.prefill_ctx)
-        total += sum(F.prm_score(p, n) for _, n, _ in self.score_rows)
+        total = fam.decode(g, self.decode_rows, self.decode_keys_sum)
+        total += sum(fam.prefill(g, n) for n in self.prefill_ctx)
+        total += sum(fam.prm_score(p, n) for _, n, _ in self.score_rows)
         total += sum(F.embed(e, len(t)) for t, _ in self.embed_rows if t)
         return total
 
@@ -634,11 +669,13 @@ def check(cell: Cell, stack: Stack, rec: Recorder, win: Window, seed: int,
     seed: ``token_gap`` (widest gap by which a served token's noisy
     reference score lies below the best), ``prm_gap`` (widest |reward -
     reference reward|), ``embed_gap`` (widest max|e - e_ref| over
-    max|e_ref|).  With ``control`` each also has the reading of the
+    max|e_ref|).  The generator's and the PRM's reference is their
+    family's.  With ``control`` each also has the reading of the
     float8 reference put in the program's place."""
     import jax
     import jax.numpy as jnp
 
+    fam = cell.family
     rng = np.random.default_rng(TF.sub_seed(seed, 4))
     want = cell.mix["check"]
     gen_c, prm_c = generator_config(cell.config), prm_config(cell.config)
@@ -696,13 +733,13 @@ def check(cell: Cell, stack: Stack, rec: Recorder, win: Window, seed: int,
         kd += [kd[0]] * (n_at - n)
         keys = split(jax.random.wrap_key_data(jnp.asarray(np.stack(kd))))
         x, at_p = jnp.asarray(_pad(seq, T)), jnp.asarray(at_p)
-        ref = np.asarray(R.token_logits(gp, gen_c, x, at_p, keys,
-                                        temperature))[:n]
+        ref = np.asarray(fam.token_logits(gp, gen_c, x, at_p, keys,
+                                          temperature))[:n]
         best = ref.max(-1)
         gaps.append(float((best - ref[np.arange(n), toks]).max()))
         if control:
-            low = np.asarray(R.token_logits(gp, gen_c, x, at_p, keys,
-                                            temperature, fp8=True))[:n]
+            low = np.asarray(fam.token_logits(gp, gen_c, x, at_p, keys,
+                                              temperature, fp8=True))[:n]
             ctrl_gaps.append(float((best - ref[np.arange(n),
                                                low.argmax(-1)]).max()))
     out["token_gap"] = {"value": max(gaps) if gaps else None,
@@ -722,10 +759,10 @@ def check(cell: Cell, stack: Stack, rec: Recorder, win: Window, seed: int,
         for r in sel:
             toks, n, got = rows[r]
             x = jnp.asarray(_pad(toks[:n], T))
-            ref = float(R.prm_reward(pp, prm_c, x, n))
+            ref = float(fam.prm_reward(pp, prm_c, x, n))
             g.append(abs(got - ref))
             if control:
-                cg.append(abs(float(R.prm_reward(pp, prm_c, x, n, fp8=True))
+                cg.append(abs(float(fam.prm_reward(pp, prm_c, x, n, fp8=True))
                               - ref))
         out["prm_gap"] = {"value": max(g), "n": len(sel)}
         if control:
@@ -792,9 +829,10 @@ def end_to_end(cell: Cell, win: Window, setup_s: float) -> Dict[str, float]:
             if vals.get(m["name"]) is not None}
 
 
-def dims(cell: Cell) -> Dict[str, F.Dims]:
-    return {"generator": F.Dims.of(generator_config(cell.config)),
-            "prm": F.Dims.of(prm_config(cell.config)),
+def dims(cell: Cell) -> Dict[str, Any]:
+    fam = cell.family
+    return {"generator": fam.dims(generator_config(cell.config)),
+            "prm": fam.dims(prm_config(cell.config)),
             "embedder": F.Dims.of(cell.config["embedder"])}
 
 
@@ -807,8 +845,9 @@ def layer_context(cell: Cell, rec: Recorder, win: Window,
         "trace": reduced, "peak": peak, "dims": d,
         "page_size": cell.config["engine"]["page_size"],
         "problem_steps": win.steps_done, "queue_wait_s": win.queue_wait_s,
-        "kv_pages": rec.kv_pages, "flops": rec.flops(d),
-        "median": statistics.median,
+        "tta_s": win.tta_s,
+        "kv_pages": rec.kv_pages, "flops": rec.flops(d, cell.family),
+        "median": statistics.median, "percentile": percentile,
     }
 
 

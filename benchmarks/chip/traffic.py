@@ -2,14 +2,16 @@
 prompts and due times out.
 
 A mix is a JSON file under ``traffic/``.  Every seed gets the same
-multiset of prompt lengths and inter-arrival gaps (quantiles of the
-stated distributions), in a seeded order, with seeded token ids: the
-seed changes which request carries which size, never how much work a
-run holds.  A closed backlog is longer than a window gets through, so
-it is made of blocks of ``max_live`` prompts, each block the same
-``max_live`` lengths spread over the range in a seeded order: the
-problems live together hold the same work for every seed, however far
-into the backlog a window gets.
+prompt lengths (spread evenly over the stated range) in a seeded
+order, with seeded token ids: the seed changes which request carries
+which size, never how much work a run holds.  Prompts come in blocks
+of ``max_live``, each block the same ``max_live`` lengths in a seeded
+order, so the problems live together (a closed backlog) or arriving
+together (an open mix) hold the same work for every seed, however far
+into the traffic a window gets.  An open mix's due times are the same
+for every seed: the quantiles of its exponential gaps in one fixed
+order, so that every seed meets the same bursts and lulls and the
+tails it reports measure the system, not the seed's arrival pattern.
 
 Keys of a mix file:
 
@@ -35,6 +37,10 @@ from dataclasses import dataclass
 from typing import List, Sequence
 
 import numpy as np
+
+
+# the seed of the one order of an open mix's gaps, whatever the run's seed
+ARRIVAL_ORDER = 0
 
 
 @dataclass
@@ -85,14 +91,10 @@ def generate(mix: dict, seed: int, seconds: float, vocab: int,
     rng = np.random.default_rng(sub_seed(seed, 3))
     n = n_requests(mix, seconds)
     lo, hi = mix["prompt_len"]
-    if mix["mode"] == "closed":
-        k = int(mix["max_live"])
-        block = np.rint(spread(lo, hi, k)).astype(int)
-        lengths = np.concatenate([block[rng.permutation(k)]
-                                  for _ in range(-(-n // k))])[:n]
-    else:
-        lengths = np.rint(spread(lo, hi, n)).astype(int)
-        lengths = lengths[rng.permutation(n)]
+    k = int(mix["max_live"])
+    block = np.rint(spread(lo, hi, k)).astype(int)
+    lengths = np.concatenate([block[rng.permutation(k)]
+                              for _ in range(-(-n // k))])[:n]
     allowed = np.setdiff1d(np.arange(vocab), np.asarray(reserved, int))
     prefix = allowed[rng.integers(0, len(allowed),
                                   size=int(mix.get("shared_prefix_len", 0)))]
@@ -101,7 +103,8 @@ def generate(mix: dict, seed: int, seconds: float, vocab: int,
                for m in lengths]
     if mix["mode"] == "closed":
         return Traffic(prompts, [0.0] * n)
-    gaps = exp_gaps(float(mix["rate_per_s"]), n)[rng.permutation(n)]
+    order = np.random.default_rng(ARRIVAL_ORDER).permutation(n)
+    gaps = exp_gaps(float(mix["rate_per_s"]), n)[order]
     due = np.cumsum(gaps)
     if "burst" in mix:
         on, off = float(mix["burst"]["on_s"]), float(mix["burst"]["off_s"])

@@ -1,6 +1,6 @@
 """A tiny benchmark root for the CPU tests: a BENCHMARK.json with one
 closed and one open cell on a 2-layer model, beside copies of the
-benchmark's own metric readers and peaks table."""
+benchmark's own metric readers, family modules and peaks table."""
 import json
 import os
 import shutil
@@ -48,8 +48,10 @@ def make_root(path, limits=LIMITS):
     d = os.path.join(path, DATA)
     for sub in ("configs", "traffic", "limits"):
         os.makedirs(os.path.join(d, sub), exist_ok=True)
-    shutil.copytree(os.path.join(REPO, DATA, "metrics"),
-                    os.path.join(d, "metrics"), dirs_exist_ok=True)
+    for sub in ("metrics", "families"):
+        shutil.copytree(os.path.join(REPO, DATA, sub), os.path.join(d, sub),
+                        dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(os.path.join(REPO, DATA, "peaks.json"), d)
     bench = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
     bench["configs"] = [{"name": "tiny", "source": "test",

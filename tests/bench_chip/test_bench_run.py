@@ -113,7 +113,8 @@ def test_measure_reports_the_cells_metrics(root):
         "bench_run_cli", os.path.join(BT.REPO, BT.DATA, "run.py"))
     run = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(run)
-    for name, trace in (("tiny-open", 0), ("tiny-closed", 1)):
+    for name, trace in (("tiny-open", 0), ("tiny-open", 1),
+                        ("tiny-closed", 1)):
         cell = H.load_cell(root, name)
         out = run.measure(cell, 2 ** 31 + 21, 1.5, trace, root=root,
                           require_tpu=False, peak=BT.PEAK)

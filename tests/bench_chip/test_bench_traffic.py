@@ -44,6 +44,21 @@ def test_closed_backlog_blocks_hold_the_same_work_for_every_seed():
                 assert sorted(lens[b:b + k]) == sorted(runs[0][:k])
 
 
+def test_open_arrivals_are_one_schedule_for_every_seed():
+    for mix in _mixes().values():
+        if mix["mode"] != "open":
+            continue
+        k = mix["max_live"]
+        runs = [TF.generate(mix, s, 45.0, 64000)
+                for s in (2 ** 31 + 31, 2 ** 31 + 32, 7)]
+        lens = [[len(p) for p in t.prompts] for t in runs]
+        assert lens[0] != lens[1] and runs[0].prompts != runs[1].prompts
+        for t, ls in zip(runs, lens):
+            assert t.due_s == runs[0].due_s
+            for b in range(0, len(ls) - k + 1, k):
+                assert sorted(ls[b:b + k]) == sorted(lens[0][b:b + k])
+
+
 def test_prompt_lengths_span_the_mix_range():
     for mix in _mixes().values():
         lo, hi = mix["prompt_len"]
