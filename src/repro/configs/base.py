@@ -40,6 +40,7 @@ class SSMConfig:
     head_dim: int = 64             # SSD / WKV head dim
     expand: int = 2                # mamba inner expansion factor
     chunk_size: int = 128          # chunked-scan block length
+    n_groups: int = 1              # mamba B/C groups (heads split evenly)
 
 
 @dataclass(frozen=True)
@@ -61,9 +62,13 @@ class ModelConfig:
     causal: bool = True
     sliding_window: int = 0        # 0 => full attention
     mrope_sections: Tuple[int, ...] = ()   # VLM M-RoPE (t, h, w) splits
-    # --- hybrid layout ---
-    attn_every: int = 0            # >0: attention applied every k-th layer
-    shared_attn_params: bool = False  # Zamba2: one attn block reused at depth
+    # --- hybrid layout (Zamba2: every layer is a Mamba2 layer; at the
+    # hybrid ids a shared attention+MLP block feeds its input) ---
+    attn_every: int = 0            # >0 and no ids: ids k-1, 2k-1, ...
+    shared_attn_params: bool = False  # blocks shared across hybrid layers
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 1        # shared blocks, used in turn
+    adapter_rank: int = 0          # per-hybrid-layer MLP adapter (0: none)
     long_context_window: int = 0   # SWA window applied only in long mode
     frontend_dim: int = 0          # stubbed modality frontend embed dim
     # --- subsystem configs ---
@@ -82,7 +87,51 @@ class ModelConfig:
     def __post_init__(self):
         assert self.arch_type in ARCH_TYPES, self.arch_type
         if self.head_dim == 0 and self.n_heads > 0:
-            object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+            object.__setattr__(self, "head_dim",
+                               self.attn_in_dim // self.n_heads)
+        if self.arch_type == "hybrid":
+            ids = self.hybrid_ids()
+            assert ids == tuple(sorted(set(ids))) and all(
+                0 <= i < self.n_layers for i in ids), ids
+            assert self.ssm.kind == "mamba2", self.ssm
+            d_in = self.ssm.expand * self.d_model
+            assert (d_in // self.ssm.head_dim) % self.ssm.n_groups == 0
+
+    # ------------------------------------------------------------------
+    # hybrid (Zamba2) layout
+    # ------------------------------------------------------------------
+    @property
+    def attn_in_dim(self) -> int:
+        """Width attention reads: a hybrid's shared block attends over
+        the hidden state joined to the input embeddings."""
+        return 2 * self.d_model if self.arch_type == "hybrid" \
+            else self.d_model
+
+    @property
+    def attn_scale(self) -> float:
+        """Softmax scale; Zamba2 scales by (head_dim / 2) ** -0.5."""
+        if self.arch_type == "hybrid":
+            return (self.head_dim / 2) ** -0.5
+        return self.head_dim ** -0.5
+
+    def hybrid_ids(self) -> Tuple[int, ...]:
+        """Layers whose Mamba2 input takes a shared block's output:
+        ``hybrid_layer_ids`` below ``n_layers``, else every
+        ``attn_every``-th layer (ids k-1, 2k-1, ...)."""
+        if self.arch_type != "hybrid":
+            return ()
+        if self.hybrid_layer_ids:
+            return tuple(i for i in self.hybrid_layer_ids
+                         if i < self.n_layers)
+        k = self.attn_every
+        return tuple(range(k - 1, self.n_layers, k)) if k > 0 else ()
+
+    @property
+    def n_mem_blocks(self) -> int:
+        """Shared blocks held: hybrid layer j uses block j mod this."""
+        n = len(self.hybrid_ids())
+        return min(self.num_mem_blocks, n) if self.shared_attn_params \
+            else n
 
     # ------------------------------------------------------------------
     # derived quantities used by the roofline + the memory cost simulator
@@ -103,22 +152,16 @@ class ModelConfig:
         """Return [(kind, count), ...] describing homogeneous layer groups.
 
         kinds: 'attn' (attention+ffn), 'mamba' (mamba mixer), 'hybrid'
-        (mamba mixer + shared attention block), 'wkv' (rwkv6 mixer +
-        channel-mix).  Groups with count>1 are scanned over stacked params.
+        (a Mamba2 layer each, fed by a shared attention block at
+        ``hybrid_ids()``), 'wkv' (rwkv6 mixer + channel-mix).  Groups
+        with count>1 are scanned over stacked params.
         """
         if self.arch_type == "ssm":
             kind = self.ssm.kind if self.ssm is not None else "rwkv6"
             return [("mamba" if kind == "mamba2" else "wkv",
                      self.n_layers)]
         if self.arch_type == "hybrid":
-            k = max(self.attn_every, 1)
-            n_super, rem = divmod(self.n_layers, k)
-            plan = []
-            if n_super > 0:
-                plan.append(("hybrid_super", n_super))  # k-1 mamba + 1 hybrid
-            if rem:
-                plan.append(("mamba", rem))
-            return plan
+            return [("hybrid", self.n_layers)]   # ids: hybrid_ids()
         return [("attn", self.n_layers)]
 
     # -- parameter count (analytic, matches the model builders) ---------
@@ -152,27 +195,44 @@ class ModelConfig:
         elif self.arch_type == "ssm":
             s = self.ssm
             if s is not None and s.kind == "mamba2":
-                d_in = s.expand * d
-                d_xbc = d_in + 2 * s.d_state
-                # z/xbc/dt projections + conv + out proj + norms
-                per = d * (d_in + d_xbc + d_in // s.head_dim) \
-                    + s.d_conv * d_xbc + d_in * d + d_in + d
+                per = self._mamba_layer_params()
             else:
                 # rwkv6: time-mix (r,k,v,g,o ~ 5 d^2) + channel mix
                 per = 5 * d * d + 2 * d * self.d_ff + 2 * d
                 per += 6 * d  # decay/bonus/token-shift params (approx)
             n += self.n_layers * per
         elif self.arch_type == "hybrid":
-            s = self.ssm
-            d_in = s.expand * d
-            mamba = d * (2 * d_in + 2 * s.d_state + d_in // s.head_dim) \
-                + d_in * d + d_in * s.d_conv + d
-            n_attn = (self.n_layers // max(self.attn_every, 1))
-            if self.shared_attn_params:
-                n_attn = min(n_attn, 1)
-            n += self.n_layers * mamba
-            n += n_attn * (attn_params() + ffn_dense(self.d_ff))
+            block, per_hybrid = self._hybrid_block_params()
+            n += self.n_layers * self._mamba_layer_params()
+            n += self.n_mem_blocks * block
+            n += len(self.hybrid_ids()) * per_hybrid
         return n
+
+    def _mamba_dims(self) -> Tuple[int, int, int]:
+        """(d_in, heads, d_xbc) of one Mamba2 mixer."""
+        s = self.ssm
+        d_in = s.expand * self.d_model
+        return d_in, d_in // s.head_dim, d_in + 2 * s.n_groups * s.d_state
+
+    def _mamba_layer_params(self) -> int:
+        """One Mamba2 layer: pre-norm, z/xBC/dt projections, conv weight
+        and bias, A_log/dt_bias/D, gated norm and output projection."""
+        d, s = self.d_model, self.ssm
+        d_in, H, d_xbc = self._mamba_dims()
+        return (d + d * (d_in + d_xbc + H) + (s.d_conv + 1) * d_xbc
+                + 3 * H + d_in + d_in * d)
+
+    def _hybrid_block_params(self) -> Tuple[int, int]:
+        """(one shared block, what each hybrid layer adds: its linear
+        and MLP adapter).  A block is the norm over [x ; x0], q/k/v from
+        that width, the output projection, the MLP norm and a gated MLP
+        with its up and gate projections fused."""
+        d, hd, f = self.d_model, self.head_dim, self.d_ff
+        a = self.attn_in_dim
+        block = (a + a * (self.n_heads + 2 * self.n_kv_heads) * hd
+                 + self.n_heads * hd * d + d + d * 2 * f + f * d)
+        per = d * d + self.adapter_rank * (d + 2 * f)
+        return block, per
 
     def active_param_count(self) -> int:
         """Params touched per token (MoE: only routed top-k experts)."""
@@ -189,7 +249,7 @@ class ModelConfig:
             return 0
         n_attn_layers = self.n_layers
         if self.arch_type == "hybrid":
-            n_attn_layers = self.n_layers // max(self.attn_every, 1)
+            n_attn_layers = len(self.hybrid_ids())
         return n_attn_layers * 2 * self.n_kv_heads * self.head_dim * bytes_per_el
 
     def state_bytes_per_branch(self, bytes_per_el: int = 4) -> int:
@@ -200,23 +260,26 @@ class ModelConfig:
         if s.kind == "rwkv6":
             n_heads = self.d_model // s.head_dim
             per_layer = n_heads * s.head_dim * s.head_dim + 2 * self.d_model
-        else:  # mamba2
-            d_in = s.expand * self.d_model
-            n_heads = d_in // s.head_dim
-            per_layer = n_heads * s.head_dim * s.d_state + d_in * s.d_conv
-        n_ssm_layers = self.n_layers
-        if self.arch_type == "hybrid":
-            n_ssm_layers = self.n_layers  # every layer has a mamba mixer
-        return n_ssm_layers * per_layer * bytes_per_el
+        else:  # mamba2: SSD state h and the conv tail of xBC
+            _, n_heads, d_xbc = self._mamba_dims()
+            per_layer = n_heads * s.head_dim * s.d_state \
+                + (s.d_conv - 1) * d_xbc
+        # a hybrid's every layer has a Mamba2 mixer
+        return self.n_layers * per_layer * bytes_per_el
 
     def flops_per_token(self, seq_len: int = 0) -> float:
-        """Approximate forward FLOPs per token (6ND/3 = 2ND + attention)."""
+        """Approximate forward FLOPs per token (6ND/3 = 2ND + attention).
+
+        A hybrid applies a shared block at every hybrid layer, so its
+        blocks count once per use, not once per copy held."""
         base = 2.0 * self.active_param_count()
+        n_attn = self.n_layers
+        if self.arch_type == "hybrid":
+            block, _ = self._hybrid_block_params()
+            n_attn = len(self.hybrid_ids())
+            base += 2.0 * (n_attn - self.n_mem_blocks) * block
         if seq_len and not self.is_attention_free:
             w = seq_len if not self.sliding_window else min(seq_len, self.sliding_window)
-            n_attn = self.n_layers
-            if self.arch_type == "hybrid":
-                n_attn = self.n_layers // max(self.attn_every, 1)
             base += 2.0 * 2.0 * n_attn * self.n_heads * self.head_dim * w
         return base
 
@@ -287,7 +350,7 @@ def tiny_variant(cfg: ModelConfig) -> ModelConfig:
     kw = dict(
         name=cfg.name + "-tiny",
         arch_type=cfg.arch_type,
-        n_layers=2 if cfg.arch_type != "hybrid" else max(2, cfg.attn_every),
+        n_layers=2 if cfg.arch_type != "hybrid" else 7,
         d_model=d_model,
         n_heads=n_heads,
         n_kv_heads=n_kv,
@@ -304,6 +367,10 @@ def tiny_variant(cfg: ModelConfig) -> ModelConfig:
         mrope_sections=(8, 4, 4) if cfg.mrope_sections else (),
         attn_every=cfg.attn_every if cfg.arch_type == "hybrid" else 0,
         shared_attn_params=cfg.shared_attn_params,
+        # a hybrid keeps two blocks in turn over uneven segments
+        hybrid_layer_ids=(1, 4, 5) if cfg.hybrid_layer_ids else (),
+        num_mem_blocks=cfg.num_mem_blocks,
+        adapter_rank=min(cfg.adapter_rank, 8),
         norm_eps=cfg.norm_eps,
         act=cfg.act,
         dtype="float32",
@@ -326,5 +393,6 @@ def tiny_variant(cfg: ModelConfig) -> ModelConfig:
             head_dim=32,
             expand=cfg.ssm.expand,
             chunk_size=32,
+            n_groups=cfg.ssm.n_groups,
         )
     return ModelConfig(**kw)

@@ -245,7 +245,13 @@ def attn_prefill(p, x, cfg, positions, cache_len: int, cache_dtype=jnp.bfloat16)
                          window=cfg.sliding_window)
         y = masked_attention(q, k, v, mask, scale=cfg.head_dim ** -0.5)
     y = y.reshape(B, S, -1) @ p["wo"]
+    return y, prefill_cache(cfg, k, v, pos2d, cache_len, cache_dtype)
 
+
+def prefill_cache(cfg, k, v, pos2d, cache_len: int, cache_dtype=jnp.bfloat16):
+    """A cache of the (possibly windowed) suffix of k/v (B,S,K,hd) at
+    positions ``pos2d`` (B,S)."""
+    B, S = pos2d.shape
     cache = init_kv_cache(cfg, B, cache_len, cache_dtype)
     if cfg.sliding_window and cache_len <= cfg.sliding_window:
         # Ring buffer: keep the last `cache_len` tokens at slot pos % len.
@@ -275,7 +281,7 @@ def attn_prefill(p, x, cfg, positions, cache_len: int, cache_dtype=jnp.bfloat16)
             cache["v"], v[:, :take].astype(cache_dtype), 0, axis=1)
         cache["pos"] = jax.lax.dynamic_update_slice_in_dim(
             cache["pos"], pos2d[:, :take], 0, axis=1)
-    return y, cache
+    return cache
 
 
 def attn_decode(p, x, cfg, cache, write_pos):
@@ -286,14 +292,21 @@ def attn_decode(p, x, cfg, cache, write_pos):
     cache is windowed, else ``pos``.
     """
     B = x.shape[0]
-    quantized = isinstance(cache["k"], dict)
-    C = (cache["k"]["q"] if quantized else cache["k"]).shape[1]
     if cfg.mrope_sections:
         positions = jnp.broadcast_to(write_pos[None, :, None], (3, B, 1))
     else:
         positions = write_pos[:, None]
     q, k, v = _project_qkv(p, x, cfg, positions)
+    y, cache = decode_cache_attend(cfg, q, k, v, cache, write_pos,
+                                   scale=cfg.head_dim ** -0.5)
+    return y.reshape(B, 1, -1) @ p["wo"], cache
 
+
+def decode_cache_attend(cfg, q, k, v, cache, write_pos, *, scale: float):
+    """Write one token's k/v (B,1,K,hd) into ``cache`` at ``write_pos``
+    and attend q (B,1,H,hd) over it.  Returns (y (B,1,H,hd), cache)."""
+    quantized = isinstance(cache["k"], dict)
+    C = (cache["k"]["q"] if quantized else cache["k"]).shape[1]
     windowed = bool(cfg.sliding_window) and C <= cfg.sliding_window
     slots = (write_pos % C) if windowed else write_pos
     # one-hot write (see attn_prefill): scatter along the sharded cache
@@ -318,7 +331,5 @@ def attn_decode(p, x, cfg, cache, write_pos):
     mask = make_mask(write_pos[:, None], pc, causal=cfg.causal,
                      window=cfg.sliding_window)
     y = masked_attention(q, _kv_resolve(kc, q.dtype),
-                         _kv_resolve(vc, q.dtype), mask,
-                         scale=cfg.head_dim ** -0.5)
-    y = y.reshape(B, 1, -1) @ p["wo"]
+                         _kv_resolve(vc, q.dtype), mask, scale=scale)
     return y, {"k": kc, "v": vc, "pos": pc}

@@ -37,9 +37,17 @@ def rms_norm(w, x, eps: float = 1e-5):
     return (y * w.astype(jnp.float32)).astype(dt)
 
 
-def rms_norm_gated(w, x, z, eps: float = 1e-5):
-    """Mamba2-style: RMSNorm(x * silu(z))."""
-    return rms_norm(w, x * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype), eps)
+def rms_norm_gated(w, x, z, eps: float = 1e-5, n_groups: int = 1):
+    """Mamba2-style: RMSNorm(x * silu(z)), the mean square taken over
+    each of ``n_groups`` equal slices of the last axis."""
+    y = x * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype)
+    if n_groups == 1:
+        return rms_norm(w, y, eps)
+    shp = y.shape
+    g = y.reshape(shp[:-1] + (n_groups, shp[-1] // n_groups))
+    g = rms_norm(jnp.ones((), jnp.float32), g, eps)
+    return (g.reshape(shp).astype(jnp.float32)
+            * w.astype(jnp.float32)).astype(x.dtype)
 
 
 def group_norm_heads(w, b, x, n_heads: int, eps: float = 1e-5):

@@ -10,7 +10,9 @@ State carried between chunks / decode steps:
   h    : (B, H, hd, ds)   SSD state
   conv : (B, d_conv-1, d_xbc) depthwise-conv tail
 
-Layout: n_groups = 1 (B/C shared across heads), as in Zamba2.
+Layout: xBC = [x (d_in) | B (G x ds) | C (G x ds)] with G = ``n_groups``;
+head n reads the B/C of group n // (H / G) (Zamba2: G = 2), and the
+gated RMSNorm takes its mean square over each of G slices of d_in.
 """
 from __future__ import annotations
 
@@ -26,8 +28,19 @@ def _dims(cfg):
     s = cfg.ssm
     d_in = s.expand * cfg.d_model
     n_heads = d_in // s.head_dim
-    d_xbc = d_in + 2 * s.d_state
+    d_xbc = d_in + 2 * s.n_groups * s.d_state
     return d_in, n_heads, d_xbc
+
+
+def _split_xbc(xBC, cfg):
+    """(..., d_xbc) -> x (..., d_in), B and C (..., G, ds)."""
+    s = cfg.ssm
+    d_in, _, _ = _dims(cfg)
+    gs = s.n_groups * s.d_state
+    lead = xBC.shape[:-1]
+    Bm = xBC[..., d_in:d_in + gs].reshape(lead + (s.n_groups, s.d_state))
+    Cm = xBC[..., d_in + gs:].reshape(lead + (s.n_groups, s.d_state))
+    return xBC[..., :d_in], Bm, Cm
 
 
 # ---------------------------------------------------------------------------
@@ -104,32 +117,32 @@ def _conv_step(p, xBC_t, conv_state):
 # ---------------------------------------------------------------------------
 
 def _ssd_chunk(carry_h, inp, *, hd: int, ds: int):
-    """One chunk.  carry_h: (B,H,hd,ds) fp32.
+    """One chunk, heads split into their B/C groups.  carry_h:
+    (B,G,Hg,hd,ds) fp32.
 
-    inp: xh (B,Q,H,hd), Bm/Cm (B,Q,ds), dA (B,Q,H) [negative log-decay*dt],
-         dt (B,Q,H).
+    inp: xh (B,Q,G,Hg,hd), Bm/Cm (B,Q,G,ds), dA (B,Q,G,Hg) [negative
+         log-decay*dt], dt (B,Q,G,Hg).
     """
     xh, Bm, Cm, dA, dt = inp
-    xdt = (xh * dt[..., None]).astype(jnp.float32)      # (B,Q,H,hd)
-    cum = jnp.cumsum(dA, axis=1)                        # (B,Q,H) (<= 0)
+    xdt = (xh * dt[..., None]).astype(jnp.float32)      # (B,Q,G,Hg,hd)
+    cum = jnp.cumsum(dA, axis=1)                        # (B,Q,G,Hg) (<= 0)
     Q = xh.shape[1]
+    Cf, Bf = Cm.astype(jnp.float32), Bm.astype(jnp.float32)
     # --- intra-chunk quadratic term -----------------------------------
-    scores = jnp.einsum("bqn,btn->bqt", Cm.astype(jnp.float32),
-                        Bm.astype(jnp.float32))         # (B,Q,Q)
-    causal = jnp.tril(jnp.ones((Q, Q), bool))[None, :, :, None]
+    scores = jnp.einsum("bqgn,btgn->bqtg", Cf, Bf)      # (B,Q,Q,G)
+    causal = jnp.tril(jnp.ones((Q, Q), bool))[None, :, :, None, None]
     # mask the exponent BEFORE exp: for t > q the argument is positive and
     # can overflow, and grad-of-where(inf) poisons the backward pass
-    delta = cum[:, :, None, :] - cum[:, None, :, :]     # (B,Q,T,H)
+    delta = cum[:, :, None] - cum[:, None, :]           # (B,Q,T,G,Hg)
     decay = jnp.where(causal, jnp.exp(jnp.where(causal, delta, 0.0)), 0.0)
-    y_intra = jnp.einsum("bqt,bqth,bthp->bqhp", scores, decay, xdt)
+    y_intra = jnp.einsum("bqtg,bqtgh,btghp->bqghp", scores, decay, xdt)
     # --- inter-chunk (state from previous chunks) ----------------------
-    y_inter = jnp.einsum("bqn,bhpn,bqh->bqhp", Cm.astype(jnp.float32),
-                         carry_h, jnp.exp(cum))
+    y_inter = jnp.einsum("bqgn,bghpn,bqgh->bqghp", Cf, carry_h,
+                         jnp.exp(cum))
     # --- state update ---------------------------------------------------
-    decay_to_end = jnp.exp(cum[:, -1:, :] - cum)        # (B,Q,H)
-    s_new = jnp.einsum("bth,bthp,btn->bhpn", decay_to_end, xdt,
-                       Bm.astype(jnp.float32))
-    chunk_decay = jnp.exp(cum[:, -1])[:, :, None, None]  # (B,H,1,1)
+    decay_to_end = jnp.exp(cum[:, -1:] - cum)           # (B,Q,G,Hg)
+    s_new = jnp.einsum("btgh,btghp,btgn->bghpn", decay_to_end, xdt, Bf)
+    chunk_decay = jnp.exp(cum[:, -1])[..., None, None]  # (B,G,Hg,1,1)
     h_next = carry_h * chunk_decay + s_new
     return h_next, (y_intra + y_inter)
 
@@ -150,7 +163,8 @@ def mamba_apply_full(p, x, cfg, state=None,
     """
     s = cfg.ssm
     d_in, H, d_xbc = _dims(cfg)
-    hd, ds = s.head_dim, s.d_state
+    hd, ds, G = s.head_dim, s.d_state, s.n_groups
+    Hg = H // G
     B, T, _ = x.shape
     if state is None:
         state = init_mamba_state(cfg, B)
@@ -166,45 +180,41 @@ def mamba_apply_full(p, x, cfg, state=None,
         idx = lengths[:, None] + jnp.arange(K - 1)[None, :]   # (B, K-1)
         conv_new = jnp.take_along_axis(
             ext, idx[..., None], axis=1).astype(state["conv"].dtype)
-    xh = xBC[..., :d_in].reshape(B, T, H, hd)
-    Bm = xBC[..., d_in:d_in + ds]
-    Cm = xBC[..., d_in + ds:]
+    xs, Bm, Cm = _split_xbc(xBC, cfg)
+    xh = xs.reshape(B, T, G, Hg, hd)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])  # (B,T,H)
     if lengths is not None:
         valid = jnp.arange(T)[None, :] < lengths[:, None]     # (B, T)
         dt = jnp.where(valid[..., None], dt, 0.0)
     A = -jnp.exp(p["A_log"])                            # (H,) negative
-    dA = dt * A                                          # (B,T,H) <= 0
+    dA = (dt * A).reshape(B, T, G, Hg)                  # <= 0
+    dt = dt.reshape(B, T, G, Hg)
 
     Q = min(s.chunk_size, T)
     pad = (-T) % Q
-    if pad:
+
+    def padded(a):
         # identity steps: dt = 0 (no state write), dA = 0 (no decay)
-        xh = jnp.pad(xh, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        Bm = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0)))
-        Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0)))
-        dA = jnp.pad(dA, ((0, 0), (0, pad), (0, 0)))
-        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
+        return jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
     Tp = T + pad
     nc = Tp // Q
 
     def body(h, chunk):
         return _ssd_chunk(h, chunk, hd=hd, ds=ds)
 
-    chunks = (
-        xh.reshape(B, nc, Q, H, hd).swapaxes(0, 1),
-        Bm.reshape(B, nc, Q, ds).swapaxes(0, 1),
-        Cm.reshape(B, nc, Q, ds).swapaxes(0, 1),
-        dA.reshape(B, nc, Q, H).swapaxes(0, 1),
-        dt.reshape(B, nc, Q, H).swapaxes(0, 1),
-    )
-    h_final, ys = jax.lax.scan(body, state["h"].astype(jnp.float32), chunks)
+    def chunked(a):
+        return padded(a).reshape((B, nc, Q) + a.shape[2:]).swapaxes(0, 1)
+
+    chunks = tuple(chunked(a) for a in (xh, Bm, Cm, dA, dt))
+    h0 = state["h"].astype(jnp.float32).reshape(B, G, Hg, hd, ds)
+    h_final, ys = jax.lax.scan(body, h0, chunks)
     y = ys.swapaxes(0, 1).reshape(B, Tp, H, hd)[:, :T]  # fp32
-    y = y + p["D"][None, None, :, None] * xh[:, :T].astype(jnp.float32)
+    y = y + p["D"][None, None, :, None] \
+        * xh.reshape(B, T, H, hd).astype(jnp.float32)
     y = y.reshape(B, T, d_in).astype(x.dtype)
-    y = rms_norm_gated(p["norm_w"], y, z, cfg.norm_eps)
+    y = rms_norm_gated(p["norm_w"], y, z, cfg.norm_eps, G)
     out = y @ p["out_proj"].astype(x.dtype)
-    return out, {"h": h_final, "conv": conv_new}
+    return out, {"h": h_final.reshape(B, H, hd, ds), "conv": conv_new}
 
 
 # ---------------------------------------------------------------------------
@@ -215,26 +225,26 @@ def mamba_decode_step(p, x, cfg, state) -> Tuple[jnp.ndarray, dict]:
     """x: (B,1,d) -> (y (B,1,d), new state)."""
     s = cfg.ssm
     d_in, H, d_xbc = _dims(cfg)
-    hd, ds = s.head_dim, s.d_state
+    hd, ds, G = s.head_dim, s.d_state, s.n_groups
     B = x.shape[0]
     z, xBC, dt_raw = _split_proj(p, x[:, 0:1], cfg)
     xBC_t, conv_new = _conv_step(p, xBC[:, 0], state["conv"])
-    xh = xBC_t[:, :d_in].reshape(B, H, hd)
-    Bm = xBC_t[:, d_in:d_in + ds]
-    Cm = xBC_t[:, d_in + ds:]
+    xs, Bm, Cm = _split_xbc(xBC_t, cfg)                 # B/C (B,G,ds)
+    xh = xs.reshape(B, G, H // G, hd)
     dt = jax.nn.softplus(dt_raw[:, 0].astype(jnp.float32) + p["dt_bias"])
     A = -jnp.exp(p["A_log"])
-    decay = jnp.exp(dt * A)                             # (B,H)
-    h = state["h"].astype(jnp.float32)
-    xdt = (xh * dt[..., None]).astype(jnp.float32)      # (B,H,hd)
+    decay = jnp.exp(dt * A).reshape(B, G, H // G)
+    h = state["h"].astype(jnp.float32).reshape(B, G, H // G, hd, ds)
+    xdt = (xh * dt.reshape(B, G, H // G)[..., None]).astype(jnp.float32)
     h_new = h * decay[..., None, None] \
-        + jnp.einsum("bhp,bn->bhpn", xdt, Bm.astype(jnp.float32))
-    y = jnp.einsum("bhpn,bn->bhp", h_new, Cm.astype(jnp.float32))
-    y = y + p["D"][None, :, None] * xh.astype(jnp.float32)
+        + jnp.einsum("bghp,bgn->bghpn", xdt, Bm.astype(jnp.float32))
+    y = jnp.einsum("bghpn,bgn->bghp", h_new, Cm.astype(jnp.float32))
+    y = y.reshape(B, H, hd) \
+        + p["D"][None, :, None] * xs.reshape(B, H, hd).astype(jnp.float32)
     y = y.reshape(B, 1, d_in).astype(x.dtype)
-    y = rms_norm_gated(p["norm_w"], y, z, cfg.norm_eps)
+    y = rms_norm_gated(p["norm_w"], y, z, cfg.norm_eps, G)
     out = y @ p["out_proj"].astype(x.dtype)
-    return out, {"h": h_new, "conv": conv_new}
+    return out, {"h": h_new.reshape(B, H, hd, ds), "conv": conv_new}
 
 
 # ---------------------------------------------------------------------------

@@ -21,8 +21,9 @@ Family specifics:
       encoder) + (Sw)iGLU/GELU MLP.
   moe     — GQA attention + sort-dispatch MoE FFN (models/moe.py).
   ssm     — RWKV6 time-mix + channel-mix (models/rwkv6.py).
-  hybrid  — Zamba2: Mamba2 backbone; one *shared* attention+MLP block
-      applied after every ``attn_every``-th mamba layer.
+  hybrid  — Zamba2: a Mamba2 layer each; at the hybrid layers a shared
+      attention+MLP block over [x ; x0] feeds the Mamba2 input
+      (models/hybrid.py).
 
 Modality frontends (audio/VLM) are stubs per the assignment: inputs carry
 precomputed frame/patch embeddings (``batch["embeds"]``) which a linear
@@ -36,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from . import attention as A
+from . import hybrid as HY
 from . import mamba2 as M
 from . import moe as MOE
 from . import rwkv6 as R
@@ -135,19 +137,18 @@ class LM:
                 groups.append(_stack_init(attn_block, next(ks), count))
             elif kind == "wkv":
                 groups.append(_stack_init(wkv_block, next(ks), count))
-            elif kind == "mamba":
+            elif kind in ("mamba", "hybrid"):
                 groups.append(_stack_init(mamba_block, next(ks), count))
-            elif kind == "hybrid_super":
-                k_inner = self.cfg.attn_every
-                inner = _stack_init(
-                    lambda kk: _stack_init(mamba_block, kk, k_inner),
-                    next(ks), count)
-                groups.append(inner)
             else:
                 raise ValueError(kind)
         p["groups"] = groups
-        if cfg.arch_type == "hybrid":
-            p["shared_attn"] = attn_block(next(ks))
+        if cfg.hybrid_ids():
+            p["shared"] = _stack_init(
+                lambda k: HY.init_shared(k, cfg, dt), next(ks),
+                cfg.n_mem_blocks)
+            p["hybrid"] = _stack_init(
+                lambda k: HY.init_hybrid_layer(k, cfg, dt), next(ks),
+                len(cfg.hybrid_ids()))
         p["ln_f"] = jnp.ones((cfg.d_model,), dt)
         if not cfg.tie_embeddings:
             p["lm_head"] = dense_init(next(ks), cfg.d_model, cfg.vocab_size, dt)
@@ -250,6 +251,37 @@ class LM:
         y, new_state = M.mamba_apply_full(blk["mamba"], h, cfg, state)
         return x + y, new_state
 
+    def _hybrid_full(self, p, gp, x, positions, states, cache_len, ckpt):
+        """The hybrid layer walk over a whole sequence.  Returns (x,
+        new Mamba2 states, per-hybrid-layer KV caches or None)."""
+        cfg = self.cfg
+        x0 = x
+        pos2d = positions if positions.ndim == 2 else positions[0]
+        kvs = []
+
+        def block(j, x):
+            blk, hl = HY.block_params(p, cfg, j)
+            q, k, v = HY.block_qkv(blk, x, x0, cfg, pos2d)
+            if x.shape[1] >= A.BLOCKED_ATTN_THRESHOLD:
+                y = A.blocked_attention(q, k, v, pos2d, pos2d, causal=True,
+                                        window=self.window,
+                                        scale=cfg.attn_scale)
+            else:
+                mask = A.make_mask(pos2d, pos2d, causal=True,
+                                   window=self.window)
+                y = A.masked_attention(q, k, v, mask, scale=cfg.attn_scale)
+            if cache_len is not None:
+                kvs.append(A.prefill_cache(
+                    _with_window(cfg, self.window), k, v, pos2d, cache_len,
+                    self.compute_dtype))
+            return HY.block_out(blk, hl, y.reshape(x.shape[:2] + (-1,)), cfg)
+
+        x, new = _hybrid_walk(
+            cfg, gp, states, x, block,
+            lambda mp, h, st: M.mamba_apply_full(mp, h, cfg, st), ckpt)
+        kv = jax.tree.map(lambda *a: jnp.stack(a), *kvs) if kvs else None
+        return _constrain_act(x), new, kv
+
     # ------------------------------------------------------------------
     # Full-sequence pass (train / prefill)
     # ------------------------------------------------------------------
@@ -303,35 +335,13 @@ class LM:
 
                 x, new_states = jax.lax.scan(body, x, (gp, gstate))
                 caches.append(new_states)
-            elif kind == "hybrid_super":
-                k_inner = cfg.attn_every
-                shared = p["shared_attn"]
+            elif kind == "hybrid":
                 if gstate is None:
-                    gstate = {
-                        "mamba": _stack_states(
-                            lambda: _stack_states(
-                                lambda: M.init_mamba_state(cfg, B), k_inner),
-                            count),
-                        "attn": None,
-                    }
-
-                @ckpt
-                def body(x, blk_state):
-                    blk, mstate = blk_state
-
-                    def inner(x, bs):
-                        b, st = bs
-                        x, new = self._mamba_layer_full(b, x, st)
-                        return _constrain_act(x), new
-
-                    x, m_new = jax.lax.scan(inner, x, (blk, mstate))
-                    x, cache, _ = self._attn_layer_full(
-                        shared, x, positions, build_cache=attn_clen)
-                    return _constrain_act(x), (m_new, cache)
-
-                x, (m_new, attn_cache) = jax.lax.scan(
-                    body, x, (gp, gstate["mamba"]))
-                caches.append({"mamba": m_new, "attn": attn_cache})
+                    gstate = _stack_states(
+                        lambda: M.init_mamba_state(cfg, B), count)
+                x, m_new, kv = self._hybrid_full(
+                    p, gp, x, positions, gstate, attn_clen, ckpt)
+                caches.append({"mamba": m_new, "attn": kv})
             else:
                 raise ValueError(kind)
         return x, caches, aux_total
@@ -364,14 +374,28 @@ class LM:
         return rms_norm(p["ln_f"], x, self.cfg.norm_eps)
 
     def reward(self, p: Params, batch: Dict[str, Any]):
-        """PRM: per-position scalar scores (B, S)."""
+        """PRM: per-position scalar scores (B, S).  A hybrid scores its
+        rows in slices (``HY.row_slice``), each row being independent."""
         assert self.with_value_head
         p = self.cast_params(p)
         x, positions = self.embed_inputs(p, batch)
-        x, _, _ = self._run_full(p, x, positions, cache_len=None, remat=False)
-        x = rms_norm(p["ln_f"], x, self.cfg.norm_eps)
-        v = (x @ p["value_head"].astype(x.dtype))[..., 0]
-        return jax.nn.sigmoid(v.astype(jnp.float32))
+
+        def score(x, positions):
+            x, _, _ = self._run_full(p, x, positions, cache_len=None,
+                                     remat=False)
+            x = rms_norm(p["ln_f"], x, self.cfg.norm_eps)
+            v = (x @ p["value_head"].astype(x.dtype))[..., 0]
+            return jax.nn.sigmoid(v.astype(jnp.float32))
+
+        B, S = x.shape[:2]
+        n = HY.row_slice(self.cfg, B, S) if self.cfg.arch_type == "hybrid" \
+            else B
+        if n == B:
+            return score(x, positions)
+        out = jax.lax.map(lambda a: score(*a),
+                          (x.reshape((B // n, n) + x.shape[1:]),
+                           positions.reshape(B // n, n, S)))
+        return out.reshape(B, S)
 
     # ------------------------------------------------------------------
     # Public: prefill
@@ -406,17 +430,16 @@ class LM:
             elif kind == "mamba":
                 caches.append(_stack_states(
                     lambda: M.init_mamba_state(cfg, batch), count))
-            elif kind == "hybrid_super":
-                k_inner = cfg.attn_every
+            elif kind == "hybrid":
+                n_kv = len(cfg.hybrid_ids())
                 caches.append({
                     "mamba": _stack_states(
-                        lambda: _stack_states(
-                            lambda: M.init_mamba_state(cfg, batch), k_inner),
-                        count),
+                        lambda: M.init_mamba_state(cfg, batch), count),
                     "attn": _stack_states(
                         lambda: A.init_kv_cache(cfg, batch, clen,
                                                 self.compute_dtype,
-                                                quant=self.quant_kv), count),
+                                                quant=self.quant_kv), n_kv)
+                    if n_kv else None,
                 })
         return {"groups": caches,
                 "next_pos": jnp.zeros((batch,), jnp.int32)}
@@ -476,30 +499,27 @@ class LM:
 
                 x, c_new = jax.lax.scan(body, x, (gp, gc))
                 new_caches.append(c_new)
-            elif kind == "hybrid_super":
-                shared = p["shared_attn"]
+            elif kind == "hybrid":
+                x0, kv_new = x, []
+                wcfg = _with_window(cfg, self.window)
 
-                def body(x, blk_state):
-                    blk, (mstate, acache) = blk_state
+                def block(j, x):
+                    blk, hl = HY.block_params(p, cfg, j)
+                    q, k, v = HY.block_qkv(blk, x, x0, cfg, write_pos[:, None])
+                    c = jax.tree.map(lambda a: a[j], gc["attn"])
+                    y, c2 = A.decode_cache_attend(wcfg, q, k, v, c, write_pos,
+                                                  scale=cfg.attn_scale)
+                    kv_new.append(c2)
+                    return HY.block_out(blk, hl, y.reshape(x.shape[0], 1, -1),
+                                        cfg)
 
-                    def inner(x, bs):
-                        b, st = bs
-                        h = rms_norm(b["ln"], x, cfg.norm_eps)
-                        y, new = M.mamba_decode_step(b["mamba"], h, cfg, st)
-                        return x + y, new
-
-                    x, m_new = jax.lax.scan(inner, x, (blk, mstate))
-                    h = rms_norm(shared["ln1"], x, cfg.norm_eps)
-                    y, a_new = self._attn_decode(shared["attn"], h, acache,
-                                                 write_pos)
-                    x = x + y
-                    h = rms_norm(shared["ln2"], x, cfg.norm_eps)
-                    x = x + mlp_apply(shared["mlp"], h, cfg.act)
-                    return x, (m_new, a_new)
-
-                x, (m_new, a_new) = jax.lax.scan(
-                    body, x, (gp, (gc["mamba"], gc["attn"])))
-                new_caches.append({"mamba": m_new, "attn": a_new})
+                x, m_new = _hybrid_walk(
+                    cfg, gp, gc["mamba"], x, block,
+                    lambda mp, h, st: M.mamba_decode_step(mp, h, cfg, st))
+                new_caches.append({
+                    "mamba": m_new,
+                    "attn": jax.tree.map(lambda *a: jnp.stack(a), *kv_new)
+                    if kv_new else None})
         logits = self.logits(p, x[:, 0])
         return logits, {"groups": new_caches, "next_pos": write_pos + 1}
 
@@ -510,6 +530,23 @@ class LM:
             # long-mode override: pretend cfg has the window for masking
             cfg = _with_window(cfg, self.window)
         return A.attn_decode(ap, h, cfg, c, write_pos)
+
+
+def _hybrid_walk(cfg, group, states, x, block, mixer, ckpt=lambda f: f):
+    """``HY.run_layers`` over the stacked layer params ``group`` and
+    states ``states``; returns (x, every layer's new state, stacked)."""
+    news = []
+
+    def mamba(x, a, b, t):
+        sl = (lambda arr: arr[a:b])
+        x, new = jax.lax.scan(ckpt(HY.mamba_layer(cfg, mixer, t)), x,
+                              (jax.tree.map(sl, group),
+                               jax.tree.map(sl, states)))
+        news.append(new)
+        return x
+
+    x = HY.run_layers(cfg, x, mamba, block)
+    return x, jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *news)
 
 
 def _with_window(cfg, window: int):

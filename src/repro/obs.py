@@ -44,6 +44,7 @@ SPANS = (
     "engine.decode.launch",    # device puts, the decode and sample dispatch
     "engine.decode.wait",      # the host blocked on the sampled tokens
     "engine.decode.commit",    # token appends, stop checks, slot frees
+    "engine.state.copy",       # recurrent-state pages copied on branch
     "runtime.gc",              # Python's cyclic collector (gc_spans only)
 )
 

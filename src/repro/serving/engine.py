@@ -300,6 +300,10 @@ class PagedEngine:
         self.swapped_in_pages = 0
         self.n_swap_outs = 0
         self.n_swap_ins = 0
+        # recurrent-state pages copied on branch, and the most state
+        # pages in use at once (0 for attention-only stacks)
+        self.state_pages_copied = 0
+        self.state_pages_peak = 0
         # ns -> [(stale page ids, PendingGather)]: the spill buffer a
         # demoted problem's pages wait in until swap-in restores them.
         # A namespace holds a *list* of segments because subtree-grained
@@ -384,7 +388,8 @@ class PagedEngine:
             x, pos = model.embed_inputs(params, {"tokens": tokens,
                                                  "positions": pos})
             ctx = PrefillCtx(positions=positions, pos=pos, pages=pages,
-                             slots=slots, lengths=lengths, state_rows=srows)
+                             slots=slots, lengths=lengths, state_rows=srows,
+                             x0=x)
             for rt in self.runtimes:
                 x, pool_k, pool_v, state = rt.prefill_into_pool(
                     params, x, ctx, pool_k, pool_v, state)
@@ -429,7 +434,7 @@ class PagedEngine:
                              slots=slots,
                              lengths=jnp.full((B,), length, jnp.int32),
                              state_rows=srows, hist_table=hist_table,
-                             hist_len=hist_len)
+                             hist_len=hist_len, x0=x)
             for rt in self.runtimes:
                 x, pool_k, pool_v, state = rt.prefill_streamed(
                     params, x, ctx, pool_k, pool_v, state)
@@ -454,7 +459,7 @@ class PagedEngine:
         cdt = jnp.float32
         x = params["embed"].astype(cdt)[tokens][:, None]   # (B,1,d)
         ctx = DecodeCtx(lengths=lengths, pages=pages, slots=slots,
-                        state_rows=srows, attend=attend)
+                        state_rows=srows, attend=attend, x0=x)
         for rt in self.runtimes:
             x, pool_k, pool_v, state = rt.decode_step(
                 params, x, ctx, pool_k, pool_v, state)
@@ -465,7 +470,7 @@ class PagedEngine:
     def _build_decode_fn(self):
         use_kernel = self.ecfg.use_kernel
         block_b = self.ecfg.kernel_block_b
-        scale = self.cfg.head_dim ** -0.5 if self.cfg.head_dim else 1.0
+        scale = self.cfg.attn_scale if self.cfg.head_dim else 1.0
 
         def step(params, tokens, block_tables, lengths, pages, slots,
                  active, srows, pool_k, pool_v, state):
@@ -492,7 +497,7 @@ class PagedEngine:
     def _build_tree_decode_fn(self):
         use_kernel = self.ecfg.use_kernel
         block_b = self.ecfg.kernel_block_b
-        scale = self.cfg.head_dim ** -0.5 if self.cfg.head_dim else 1.0
+        scale = self.cfg.attn_scale if self.cfg.head_dim else 1.0
 
         def step(params, tokens, lengths, pages, slots, active,
                  page_list, page_mask, page_lens, srows, pool_k, pool_v,
@@ -610,6 +615,7 @@ class PagedEngine:
             spages = self.state.alloc(len(handles))   # zeroed at alloc
             for h, pg in zip(handles, spages):
                 self.state_of[h.seq_id] = pg
+            self._note_state_peak()
         for h, t in zip(handles, all_toks):
             self.tokens[h.seq_id] = t
         pct = self.ecfg.prefill_chunk_tokens
@@ -762,11 +768,18 @@ class PagedEngine:
         if self.state is not None:
             # recurrent state has no prefix sharing: every branch eagerly
             # copies the parent's constant-size page (copy-on-branch)
-            pages = self.state.alloc(len(handles))
-            self.state.copy_page(self.state_of[seq_id], pages)
+            with span("engine.state.copy"):
+                pages = self.state.alloc(len(handles))
+                self.state.copy_page(self.state_of[seq_id], pages)
             for b, pg in zip(handles, pages):
                 self.state_of[b.seq_id] = pg
+            self.state_pages_copied += len(pages)
+            self._note_state_peak()
         return [b.seq_id for b in handles]
+
+    def _note_state_peak(self) -> None:
+        used = self.state.dump_page - self.state.n_free
+        self.state_pages_peak = max(self.state_pages_peak, used)
 
     def free(self, seq_id: int) -> None:
         h = self.alloc.seqs.get(seq_id)
@@ -882,6 +895,7 @@ class PagedEngine:
                         npages, {k: a[:, rows] for k, a in host.items()})
                     for pg, j in zip(npages, rows):
                         self.state_of[seg_ids[j]] = pg
+            self._note_state_peak()
         self._drop_spill(ns)
         self.swapped_in_pages += restored
         self.n_swap_ins += 1
@@ -925,6 +939,10 @@ class PagedEngine:
         self.swapped_in_pages = 0
         self.n_swap_outs = 0
         self.n_swap_ins = 0
+        self.state_pages_copied = 0
+        self.state_pages_peak = 0
+        if self.state is not None:
+            self._note_state_peak()
         self.unique_pages_streamed = 0
         self.logical_pages_streamed = 0
         self.unique_pages_streamed_by_ns.clear()

@@ -17,10 +17,10 @@ stream (and the pools) through the stack:
     Constant-size per-sequence state lives in a :class:`StatePool`
     (kvcache.pool): one state page per sequence, copy-on-branch, so
     tree search's branch/prune/swap/demote machinery works unchanged.
-  * :class:`HybridRuntime`     — Zamba2 super-layers: ``attn_every``
-    mamba mixers followed by one *shared* attention+MLP block whose KV
-    goes through the paged pool (KV pool depth = number of
-    super-layers).
+  * :class:`HybridRuntime`     — Zamba2: a Mamba2 layer each, and at
+    every hybrid layer a shared attention+MLP block over ``[x ; x0]``
+    (models/hybrid.py) whose KV goes through the paged pool (KV pool
+    depth = number of hybrid layers).
 
 Each runtime exposes three jit-traceable methods (called inside the
 engine's jitted steps — arguments are tracers):
@@ -59,6 +59,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import attention as A
+from repro.models import hybrid as HY
 from repro.models import mamba2 as M
 from repro.models import moe as MOE
 from repro.models import rwkv6 as R
@@ -77,6 +78,7 @@ class DecodeCtx:
     slots: Any            # (B,) in-page write slot
     state_rows: Any       # (B,) state page per row (dump for inactive)
     attend: Any           # attend(kv_layer, q (B,H,hd), pool_k, pool_v)
+    x0: Any = None        # (B,1,d) input embeddings (hybrid blocks)
 
 
 @dataclass
@@ -90,6 +92,7 @@ class PrefillCtx:
     state_rows: Any       # (B,) state page per row
     hist_table: Any = None   # streamed only: (B,Tp) pow2-padded block table
     hist_len: Any = None     # streamed only: tokens already in the pool
+    x0: Any = None           # (B,T,d) input embeddings (hybrid blocks)
 
 
 # ---------------------------------------------------------------------------
@@ -121,14 +124,11 @@ def _attn_decode_layer(cfg, blk, x, ctx, kv_l, pool_k, pool_v, ffn):
     return x + ffn(blk, h), pool_k, pool_v
 
 
-def _attn_prefill_layer(cfg, blk, x, ctx, kv_l, pool_k, pool_v, ffn, *,
-                        dense: bool, use_kernel: bool):
-    """One attention layer of a one-shot prefill bucket (historical
-    ``_build_prefill_fn`` iteration)."""
-    B, T = x.shape[:2]
-    scale = cfg.head_dim ** -0.5
-    h = rms_norm(blk["ln1"], x, cfg.norm_eps)
-    q, k, v = A._project_qkv(blk["attn"], h, cfg, ctx.pos)
+def _prefill_attend(cfg, q, k, v, ctx, kv_l, pool_k, pool_v, *,
+                    dense: bool, use_kernel: bool):
+    """Write a prefill bucket's K/V into the pool pages, then attend
+    within the bucket.  Returns (y (B,T,H,hd), pool_k, pool_v)."""
+    scale = cfg.attn_scale
     pool_k = pool_k.at[kv_l, ctx.pages, ctx.slots].set(k.astype(pool_k.dtype))
     pool_v = pool_v.at[kv_l, ctx.pages, ctx.slots].set(v.astype(pool_v.dtype))
     if dense:
@@ -143,6 +143,19 @@ def _attn_prefill_layer(cfg, blk, x, ctx, kv_l, pool_k, pool_v, ffn, *,
         y = A.blocked_attention(q, k, v, ctx.positions, ctx.positions,
                                 causal=cfg.causal, window=cfg.sliding_window,
                                 scale=scale)
+    return y, pool_k, pool_v
+
+
+def _attn_prefill_layer(cfg, blk, x, ctx, kv_l, pool_k, pool_v, ffn, *,
+                        dense: bool, use_kernel: bool):
+    """One attention layer of a one-shot prefill bucket (historical
+    ``_build_prefill_fn`` iteration)."""
+    B, T = x.shape[:2]
+    h = rms_norm(blk["ln1"], x, cfg.norm_eps)
+    q, k, v = A._project_qkv(blk["attn"], h, cfg, ctx.pos)
+    y, pool_k, pool_v = _prefill_attend(cfg, q, k, v, ctx, kv_l, pool_k,
+                                        pool_v, dense=dense,
+                                        use_kernel=use_kernel)
     x = x + y.reshape(B, T, -1) @ blk["attn"]["wo"]
     h = rms_norm(blk["ln2"], x, cfg.norm_eps)
     return x + ffn(blk, h), pool_k, pool_v
@@ -164,16 +177,13 @@ def _streamed_hist(cfg, ctx, page_size: int):
     return hist_idx, jnp.concatenate([mask_h, mask_s], axis=-1)
 
 
-def _attn_streamed_layer(cfg, blk, x, ctx, kv_l, pool_k, pool_v, ffn,
-                         hist_idx, mask):
-    """One attention layer of a page-streamed prefill segment
-    (historical ``_build_streamed_prefill_fn`` iteration)."""
-    B, Ts = x.shape[:2]
-    scale = cfg.head_dim ** -0.5
+def _streamed_attend(cfg, q, k, v, ctx, kv_l, pool_k, pool_v, hist_idx,
+                     mask):
+    """Write a streamed segment's K/V into the pool, then attend over the
+    prompt's history gathered from the pool and the segment itself.
+    Returns (y (B,Ts,H,hd), pool_k, pool_v)."""
     P = pool_k.shape[1]
     ps = pool_k.shape[2]
-    h = rms_norm(blk["ln1"], x, cfg.norm_eps)
-    q, k, v = A._project_qkv(blk["attn"], h, cfg, ctx.pos)
     pool_k = pool_k.at[kv_l, ctx.pages, ctx.slots].set(k.astype(pool_k.dtype))
     pool_v = pool_v.at[kv_l, ctx.pages, ctx.slots].set(v.astype(pool_v.dtype))
     K, hd = k.shape[2], k.shape[3]
@@ -183,7 +193,19 @@ def _attn_streamed_layer(cfg, blk, x, ctx, kv_l, pool_k, pool_v, ffn,
     hv = flat_v[hist_idx]
     kk = jnp.concatenate([hk.astype(k.dtype), k], axis=1)
     vv = jnp.concatenate([hv.astype(v.dtype), v], axis=1)
-    y = A.masked_attention(q, kk, vv, mask, scale=scale)
+    y = A.masked_attention(q, kk, vv, mask, scale=cfg.attn_scale)
+    return y, pool_k, pool_v
+
+
+def _attn_streamed_layer(cfg, blk, x, ctx, kv_l, pool_k, pool_v, ffn,
+                         hist_idx, mask):
+    """One attention layer of a page-streamed prefill segment
+    (historical ``_build_streamed_prefill_fn`` iteration)."""
+    B, Ts = x.shape[:2]
+    h = rms_norm(blk["ln1"], x, cfg.norm_eps)
+    q, k, v = A._project_qkv(blk["attn"], h, cfg, ctx.pos)
+    y, pool_k, pool_v = _streamed_attend(cfg, q, k, v, ctx, kv_l, pool_k,
+                                         pool_v, hist_idx, mask)
     x = x + y.reshape(B, Ts, -1) @ blk["attn"]["wo"]
     h = rms_norm(blk["ln2"], x, cfg.norm_eps)
     return x + ffn(blk, h), pool_k, pool_v
@@ -406,97 +428,121 @@ class RecurrentRuntime(LayerRuntime):
 
 
 class HybridRuntime(LayerRuntime):
-    """Zamba2 super-layers: ``attn_every`` mamba mixers (inner scan,
-    exactly ``LM.decode_step``'s) followed by the *shared* attention+MLP
-    block served through the paged pool — KV pool layer ``kv_offset+l``
-    holds super-layer ``l``'s shared-attention KV."""
+    """Zamba2 (models/hybrid.py): every layer a Mamba2 layer with its
+    state in the StatePool, and at each hybrid layer a shared block
+    over ``[x ; ctx.x0]`` served through the paged pool — KV pool layer
+    ``kv_offset + j`` holds the j-th hybrid layer's KV.  The layer walk,
+    block and Mamba2 mixers are ``LM``'s own."""
 
     kind = "hybrid"
 
     def __init__(self, model, ecfg, gi: int, count: int, kv_offset: int):
         super().__init__(model, ecfg, gi, count)
         self.kv_offset = kv_offset
-        self.n_kv_layers = count
-        self.k_inner = self.cfg.attn_every
+        self.n_kv_layers = len(self.cfg.hybrid_ids())
         self._dense = ecfg.prefill == "dense"
         self._use_kernel = ecfg.use_kernel
         self._page_size = ecfg.page_size
         self._proto = M.init_mamba_state(self.cfg, 1)
         self._state_names = tuple(sorted(self._proto))
+        # a page holds h as (hd, d_state, heads) and the conv tail flat:
+        # the layouts the mixer computes in, so that the compiler does not
+        # re-lay the whole pool out at every step
+        h, conv = self._proto["h"].shape[1:], self._proto["conv"].shape[1:]
+        self._page = {"h": (h[1], h[2], h[0]), "conv": (conv[0] * conv[1],)}
 
     def state_specs(self):
-        L = self.count * self.k_inner
-        return {f"{self.gi}:{n}": (L, v.shape[1:], v.dtype)
+        return {f"{self.gi}:{n}": (self.count, self._page[n], v.dtype)
                 for n, v in self._proto.items()}
 
-    def _mamba_states(self, state, rows):
-        """(count*k_inner, B, ...) -> per-super (count, k_inner, B, ...)."""
-        g = self._gather_state(state, rows)
-        return {n: a.reshape((self.count, self.k_inner) + a.shape[1:])
-                for n, a in g.items()}
+    def _from_page(self, name, a):
+        """(B, *page) -> the mixer's (B, *state) layout."""
+        if name == "h":
+            return jnp.moveaxis(a, -1, 1)
+        return a.reshape((-1,) + self._proto[name].shape[1:])
 
-    def _run(self, params, x, ctx, pool_k, pool_v, state, inner_body,
-             attn_layer):
-        """Common driver: per super-layer, inner mamba scan then the
-        shared attention block."""
+    def _to_page(self, name, a):
+        if name == "h":
+            return jnp.moveaxis(a, 1, -1)
+        return a.reshape((-1,) + self._page[name])
+
+    def _run(self, params, x, ctx, pool_k, pool_v, state, positions, mixer,
+             attend):
+        """The layer walk over tokens at ``positions`` (B, T):
+        ``attend(kv_layer, q, k, v, pool_k, pool_v) -> (y, pool_k,
+        pool_v)`` is a hybrid layer's attention."""
         cfg = self.cfg
-        gp = params["groups"][self.gi]       # leaves (count, k_inner, ...)
-        shared = params["shared_attn"]
-        gstate = self._mamba_states(state, ctx.state_rows)
-        news = []
-        for l in range(self.count):
-            blk = jax.tree.map(lambda a: a[l], gp)
-            mstate = {n: a[l] for n, a in gstate.items()}
-            x, m_new = jax.lax.scan(inner_body, x, (blk, mstate))
-            news.append(m_new)
-            x, pool_k, pool_v = attn_layer(
-                cfg, shared, x, ctx, self.kv_offset + l, pool_k, pool_v,
-                lambda b, h: mlp_apply(b["mlp"], h, cfg.act))
-        new = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *news)
-        state = self._scatter_state(state, ctx.state_rows, new)
-        return x, pool_k, pool_v, state
+        pools = [pool_k, pool_v]
 
-    def _inner_decode(self):
-        cfg = self.cfg
+        def block(j, x):
+            blk, hl = HY.block_params(params, cfg, j)
+            q, k, v = HY.block_qkv(blk, x, ctx.x0, cfg, positions)
+            y, pools[0], pools[1] = attend(self.kv_offset + j, q, k, v,
+                                           *pools)
+            return HY.block_out(blk, hl, y.reshape(x.shape[:2] + (-1,)),
+                                cfg)
 
-        def body(x, bs):
-            b, st = bs
-            h = rms_norm(b["ln"], x, cfg.norm_eps)
-            y, new = M.mamba_decode_step(b["mamba"], h, cfg, st)
-            return x + y, new
-        return body
+        # each layer reads and writes its rows' state pages inside the
+        # layer scan, so one layer's states are in flight at a time
+        rows, gp = ctx.state_rows, params["groups"][self.gi]
+        keys = {n: f"{self.gi}:{n}" for n in self._state_names}
+        state = dict(state)
 
-    def _inner_prefill(self, lengths):
-        cfg = self.cfg
+        def mamba(x, a, b, t):
+            layer = HY.mamba_layer(cfg, mixer, t)
 
-        def body(x, bs):
-            b, st = bs
-            h = rms_norm(b["ln"], x, cfg.norm_eps)
-            y, new = M.mamba_apply_full(b["mamba"], h, cfg, st,
-                                        lengths=lengths)
-            return x + y, new
-        return body
+            def body(carry, inp):
+                x, st = carry
+                blk, l = inp
+                x, new = layer(x, (blk, {n: self._from_page(n, st[k][l, rows])
+                                         for n, k in keys.items()}))
+                return (x, {k: st[k].at[l, rows].set(self._to_page(
+                    n, new[n]).astype(st[k].dtype))
+                    for n, k in keys.items()}), None
+
+            (x, st), _ = jax.lax.scan(
+                body, (x, {k: state[k] for k in keys.values()}),
+                (jax.tree.map(lambda arr: arr[a:b], gp), jnp.arange(a, b)))
+            state.update(st)
+            return x
+
+        x = HY.run_layers(cfg, x, mamba, block)
+        return x, pools[0], pools[1], state
 
     def decode_step(self, params, x, ctx, pool_k, pool_v, state):
+        cfg = self.cfg
+
+        def attend(kv_l, q, k, v, pk, pv):
+            pk = pk.at[kv_l, ctx.pages, ctx.slots].set(k[:, 0])
+            pv = pv.at[kv_l, ctx.pages, ctx.slots].set(v[:, 0])
+            return ctx.attend(kv_l, q[:, 0], pk, pv), pk, pv
+
         return self._run(params, x, ctx, pool_k, pool_v, state,
-                         self._inner_decode(), _attn_decode_layer)
+                         ctx.lengths[:, None],
+                         lambda mp, h, st: M.mamba_decode_step(mp, h, cfg, st),
+                         attend)
+
+    def _prefill_mixer(self, ctx):
+        cfg = self.cfg
+        return lambda mp, h, st: M.mamba_apply_full(mp, h, cfg, st,
+                                                    lengths=ctx.lengths)
 
     def prefill_into_pool(self, params, x, ctx, pool_k, pool_v, state):
-        def attn_layer(cfg, blk, x, ctx, kv_l, pk, pv, ffn):
-            return _attn_prefill_layer(cfg, blk, x, ctx, kv_l, pk, pv, ffn,
-                                       dense=self._dense,
-                                       use_kernel=self._use_kernel)
+        def attend(kv_l, q, k, v, pk, pv):
+            return _prefill_attend(self.cfg, q, k, v, ctx, kv_l, pk, pv,
+                                   dense=self._dense,
+                                   use_kernel=self._use_kernel)
         return self._run(params, x, ctx, pool_k, pool_v, state,
-                         self._inner_prefill(ctx.lengths), attn_layer)
+                         ctx.positions, self._prefill_mixer(ctx), attend)
 
     def prefill_streamed(self, params, x, ctx, pool_k, pool_v, state):
         hist_idx, mask = _streamed_hist(self.cfg, ctx, self._page_size)
 
-        def attn_layer(cfg, blk, x, ctx, kv_l, pk, pv, ffn):
-            return _attn_streamed_layer(cfg, blk, x, ctx, kv_l, pk, pv, ffn,
-                                        hist_idx, mask)
+        def attend(kv_l, q, k, v, pk, pv):
+            return _streamed_attend(self.cfg, q, k, v, ctx, kv_l, pk, pv,
+                                    hist_idx, mask)
         return self._run(params, x, ctx, pool_k, pool_v, state,
-                         self._inner_prefill(ctx.lengths), attn_layer)
+                         ctx.positions, self._prefill_mixer(ctx), attend)
 
 
 # ---------------------------------------------------------------------------
@@ -516,7 +562,7 @@ def build_runtimes(model, ecfg):
             kv_offset += rt.n_kv_layers
         elif kind in ("wkv", "mamba"):
             rt = RecurrentRuntime(model, ecfg, gi, count, flavor=kind)
-        elif kind == "hybrid_super":
+        elif kind == "hybrid":
             rt = HybridRuntime(model, ecfg, gi, count, kv_offset)
             kv_offset += rt.n_kv_layers
         else:

@@ -12,6 +12,7 @@ import pytest
 
 from repro import obs
 from repro.configs import get_config
+from repro.configs.base import ModelConfig, SSMConfig
 from repro.core import (ETSConfig, Request, SearchConfig, ServingConfig,
                         ServingLoop)
 from repro.models.model import build_model
@@ -35,8 +36,10 @@ def tiny_models():
             (emb, emb.init(jax.random.key(2))))
 
 
-def _backend(tiny_models):
+def _backend(tiny_models, generator=None):
     (lm, lm_p), (prm, prm_p), (emb, emb_p) = tiny_models
+    if generator is not None:
+        lm, lm_p = generator
     engine = PagedEngine(lm, lm_p, EngineConfig(
         n_pages=256, page_size=8, max_batch=16, max_seq_len=128,
         attention="tree"))
@@ -74,7 +77,10 @@ PARENTS = {
     "engine.decode.launch": DECODE,
     "engine.decode.wait": DECODE,
     "engine.decode.commit": DECODE,
+    "engine.state.copy": {"backend.expand_begin"},
 }
+# spans only a generator that keeps recurrent state opens
+STATE_SPANS = {"engine.state.copy"}
 
 
 def _program_spans(trace_dir):
@@ -104,23 +110,29 @@ def _parents(spans):
     return out
 
 
-def test_profiled_serving_run_emits_every_span_nested(tiny_models,
-                                                      tmp_path):
-    backend = _backend(tiny_models)
+def _profiled_run(backend, trace_dir):
+    """A profiled serving run; returns (loop, the spans seen)."""
     loop = ServingLoop(backend, SCFG, [Request(prompt=p) for p in PROMPTS],
                        max_live=2, cfg=ServingConfig(refill=True))
     loop.submit(len(PROMPTS), Request(prompt=PROMPTS[0]))
-    with jax.profiler.trace(str(tmp_path)), obs.gc_spans():
+    with jax.profiler.trace(trace_dir), obs.gc_spans():
         while loop.tick():
             pass
         gc.collect()
     seen = set()
-    for spans in _program_spans(str(tmp_path)):
+    for spans in _program_spans(trace_dir):
         for name, parent in _parents(spans):
             seen.add(name)
             if name != "runtime.gc":
                 assert parent in PARENTS[name], (name, parent)
-    assert seen == set(obs.SPANS)
+    return loop, seen
+
+
+def test_profiled_serving_run_emits_every_span_nested(tiny_models,
+                                                      tmp_path):
+    backend = _backend(tiny_models)
+    loop, seen = _profiled_run(backend, str(tmp_path))
+    assert seen == set(obs.SPANS) - STATE_SPANS
     # the loop's host stamps, beside its virtual clock
     slo = loop.slo
     assert set(slo.finished_wall) == set(range(len(PROMPTS) + 1))
@@ -150,3 +162,29 @@ def test_prm_counters_follow_the_bucket_arithmetic(tiny_models):
     assert backend.n_scored_rows == 4
     assert backend.n_scored_tokens == 17 + 23 + 9 + 9
     assert backend.n_scored_padded_tokens == 4 * 32 + 1 * 16
+
+
+def test_state_copy_span_and_counters(tiny_models, tmp_path):
+    """A generator with recurrent state (a Mamba2/attention hybrid)
+    opens every span, copy-on-branch among them, and counts the state
+    pages it copied and the most it held at once."""
+    cfg = ModelConfig(
+        name="obs-hybrid", arch_type="hybrid", n_layers=2, d_model=32,
+        n_heads=2, n_kv_heads=2, d_ff=64, vocab_size=64, attn_every=2,
+        shared_attn_params=True, dtype="float32",
+        ssm=SSMConfig(kind="mamba2", d_state=8, d_conv=4, head_dim=16,
+                      expand=2, chunk_size=16))
+    lm = build_model(cfg, remat=False)
+    backend = _backend(tiny_models, (lm, lm.init(jax.random.key(3))))
+    eng = backend.engine
+    branched = []
+    branch = eng.branch
+    eng.branch = lambda sid, n: branched.append(n) or branch(sid, n)
+    _, seen = _profiled_run(backend, str(tmp_path))
+    assert seen == set(obs.SPANS)
+    assert eng.state_pages_copied == sum(branched) > 0
+    # two live problems of at most 4 kept leaves and 4 children each
+    assert 4 < eng.state_pages_peak <= 2 * 2 * SCFG.width
+    assert eng.state.n_free == eng.state.dump_page
+    eng.reset_counters()
+    assert eng.state_pages_copied == eng.state_pages_peak == 0
